@@ -1,9 +1,5 @@
 # Verification entry points. `make verify` = tier-1 tests + serving benchmark.
 #
-# Note: the sharding tests (tests/test_shard*.py) are known to fail on
-# single-device containers; run `make verify-core` for the gate that must
-# stay green everywhere.
-#
 # CI splits the gate in four (see .github/workflows/ci.yml):
 #   verify-core-tests — everything except the serving-regression suite and
 #                       the kernel/pool suite (each has its own job);
@@ -49,10 +45,10 @@ DISAGG_TESTS := tests/test_disagg.py
 
 # the verify-kernels suite (its own CI job; ignored by verify-core-tests so
 # nothing runs twice): TailPool/DeviceTailPool equivalence tests, the
-# device-pool no-reupload/swap tests, and the decode_attention ragged-batch
-# kernel sweep
+# device-pool no-reupload/swap tests, the decode_attention ragged-batch
+# kernel sweep, and the AOT compiles of the kernels for a described v5e
 KERNEL_TESTS := tests/test_kernels.py tests/test_tail_pool.py \
-	tests/test_device_pool.py
+	tests/test_device_pool.py tests/test_tpu_compile.py
 
 # three-tier content-addressed prefix store: segment-log layout/compaction,
 # the HBM->DRAM->SSD demotion cascade, digest refcounts/dedup and the

@@ -60,7 +60,7 @@ def build_real_session(
 
     _, kvs = T.forward(
         params, {"tokens": jnp.asarray(prefix_tokens)[None]}, cfg,
-        block_q=min(512, max(16, n)), return_kv=True,
+        block_q=min(512, max(16, n)), logits_positions="last", return_kv=True,
     )
     k_all = np.asarray(kvs[0][:, 0], dtype=np.float16)  # (L, n, n_kv, d)
     v_all = np.asarray(kvs[1][:, 0], dtype=np.float16)

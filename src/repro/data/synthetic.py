@@ -10,7 +10,8 @@ attention for the quality benchmarks.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Iterator, List, Tuple
+import zlib
+from typing import Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -46,14 +47,23 @@ def _example(rng, vocab: int, body_len: int, cls: int, n_classes: int) -> np.nda
     return np.concatenate([body, [SEP, LABEL_BASE + cls, SEP]])
 
 
-def make_task(name: str, vocab: int, *, n_queries: int = 16, seed: int = 0) -> FewShotTask:
+def make_task(name: str, vocab: int, *, n_queries: int = 16, seed: int = 0,
+              prefix_len: Optional[int] = None) -> FewShotTask:
+    """The dataset's few-shot task, the same in every process for one seed.
+
+    ``prefix_len`` draws as many shots as a ``prefix_len``-token prefix needs
+    and cuts the last one short; None keeps the dataset's own shot count."""
     spec = DATASETS[name]
-    rng = np.random.default_rng((seed, hash(name) & 0xFFFF))
+    # crc32, not hash(): str hashes are salted per process (PYTHONHASHSEED)
+    rng = np.random.default_rng((seed, zlib.crc32(name.encode()) & 0xFFFF))
     n_cls = spec["n_classes"]
+    n_shots = spec["examples"]
+    if prefix_len is not None:
+        n_shots = -(-prefix_len // (spec["body_len"] + 3))
     shots = []
-    for i in range(spec["examples"]):
+    for i in range(n_shots):
         shots.append(_example(rng, vocab, spec["body_len"], i % n_cls, n_cls))
-    prefix = np.concatenate(shots)
+    prefix = np.concatenate(shots)[:prefix_len]
     queries = []
     for _ in range(n_queries):
         cls = int(rng.integers(n_cls))

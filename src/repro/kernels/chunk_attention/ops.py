@@ -12,14 +12,11 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import _default_interpret
 from repro.kernels.chunk_attention.kernel import chunk_attention as _kernel
 from repro.kernels.chunk_attention.ref import chunk_attention_ref, merge_partials
 
 NEG_INF = -1e30
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _suffix_partial(q, k_suf, v_suf):

@@ -5,12 +5,9 @@ from functools import partial
 
 import jax
 
+from repro.kernels import _default_interpret
 from repro.kernels.chunk_score.kernel import chunk_score as _kernel
 from repro.kernels.chunk_score.ref import chunk_score_ref
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @partial(jax.jit, static_argnames=("chunk_tokens", "block_k", "use_kernel"))

@@ -5,11 +5,21 @@ scattered physical pages (= ContiguousChunks); the page table is a
 scalar-prefetch operand so the BlockSpec gathers pages by indirection.
 Online softmax across pages in fp32 VMEM scratch.
 
+Grid = (b, n_active): one grid step reads one whole physical page, every KV
+head at once, and updates every query head.  The pool is viewed as
+``(b, n_pages, page * n_kv, d)`` (a free row-major reshape), so a page is one
+``(page * n_kv, d)`` tile whose last two dims are the array's own — the block
+shape the TPU's tiling accepts for any ``n_kv``.  Row ``r`` of the tile is
+token ``r // n_kv`` of KV head ``r % n_kv``; every query head scores the whole
+tile and the columns of the other KV heads are masked, so each page crosses
+HBM once per request instead of once per query head.
+
 Besides the attention output, the kernel returns the per-page attention
 probability mass (the attention-guided cache's A_j signal): a running
-raw-mass scratch is rescaled by the same alpha as the softmax accumulator
-and normalized by the final denominator at the last grid step, so the
-engine no longer recomputes scores a second time to extract it.
+raw-mass scratch is rescaled by the same alpha as the softmax accumulator,
+the current page's mass lands in its column through a one-hot lane mask,
+and the whole row is normalized by the final denominator at the last grid
+step, so the engine never recomputes scores a second time to extract it.
 
 Ragged batches: requests with fewer active pages than the table width mark
 the pad slots with a negative table entry.  A pad page contributes exactly
@@ -34,10 +44,9 @@ NEG_INF = -1e30
 
 def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref, mass_ref,
                    m_scr, l_scr, acc_scr, mass_scr, *, scale: float, page: int,
-                   n_active: int, n_heads: int):
-    bh = pl.program_id(0)
+                   n_kv: int, group: int, n_active: int):
+    b = pl.program_id(0)
     j = pl.program_id(1)
-    b = bh // n_heads
 
     @pl.when(j == 0)
     def _init():
@@ -46,32 +55,38 @@ def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref, mass_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
         mass_scr[...] = jnp.zeros_like(mass_scr)
 
-    q = q_ref[0].astype(jnp.float32)  # (1, d)
-    k = k_ref[0, 0, :, 0].astype(jnp.float32)  # (page, d)
-    v = v_ref[0, 0, :, 0].astype(jnp.float32)
+    q = q_ref[0].astype(jnp.float32)  # (n_q, d)
+    k = k_ref[0, 0].astype(jnp.float32)  # (page * n_kv, d)
+    v = v_ref[0, 0].astype(jnp.float32)
     s_mat = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-    pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-    valid = (pos < len_ref[b]) & (tbl_ref[b, j] >= 0)
+    shape = s_mat.shape  # (n_q, page * n_kv)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    pos = j * page + col // n_kv
+    valid = ((col % n_kv == row // group) & (pos < len_ref[b])
+             & (tbl_ref[b, j] >= 0))
     s_mat = jnp.where(valid, s_mat, NEG_INF)
 
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s_mat, axis=-1, keepdims=True))
     p = jnp.exp(s_mat - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+    p_sum = jnp.sum(p, axis=-1, keepdims=True)  # (n_q, 1)
+    l_scr[...] = alpha * l_scr[...] + p_sum
     acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    # per-page raw mass, kept in the running max's units (same rescale)
-    mass_scr[...] = mass_scr[...] * alpha[0, 0]
-    mass_scr[0, j] = jnp.sum(p)
+    # per-page raw mass, kept in the running max's units (same rescale);
+    # page j's mass enters its own column through a one-hot lane mask
+    here = jax.lax.broadcasted_iota(jnp.int32, mass_scr.shape, 1) == j
+    mass_scr[...] = jnp.where(here, p_sum, mass_scr[...] * alpha)
     m_scr[...] = m_new
 
     @pl.when(j == n_active - 1)
     def _done():
         denom = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
-        mass_ref[0, 0] = mass_scr[0] / denom[0, 0]
+        mass_ref[0] = mass_scr[...] / denom
 
 
 def decode_attention(
@@ -92,40 +107,39 @@ def decode_attention(
     b, n_q, d = q.shape
     _, n_pages, page, n_kv, _ = k_pool.shape
     n_active = page_table.shape[1]
-    group = n_q // n_kv
+    rows = page * n_kv
+    k_tiles = k_pool.reshape(b, n_pages, rows, d)
+    v_tiles = v_pool.reshape(b, n_pages, rows, d)
 
     kernel = functools.partial(
-        _decode_kernel, scale=d ** -0.5, page=page, n_active=n_active,
-        n_heads=n_q)
+        _decode_kernel, scale=d ** -0.5, page=page, n_kv=n_kv,
+        group=n_q // n_kv, n_active=n_active)
+
+    # pad slots (table entry < 0) clamp their gather to page 0; the kernel
+    # masks the loaded data, so the page read is arbitrary
+    def page_map(bi, j, tbl, ln):
+        return bi, jnp.maximum(tbl[bi, j], 0), 0, 0
+
+    def per_request(bi, j, tbl, ln):
+        return bi, 0, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b * n_q, n_active),
+        grid=(b, n_active),
         in_specs=[
-            pl.BlockSpec((1, 1, d), lambda bh, j, tbl, ln, nh=n_q: (bh // nh, bh % nh, 0)),
-            # pad slots (table entry < 0) clamp their gather to page 0; the
-            # kernel masks the loaded data, so the page read is arbitrary
-            pl.BlockSpec(
-                (1, 1, page, 1, d),
-                lambda bh, j, tbl, ln, nh=n_q, g=group: (
-                    bh // nh, jnp.maximum(tbl[bh // nh, j], 0), 0,
-                    (bh % nh) // g, 0)),
-            pl.BlockSpec(
-                (1, 1, page, 1, d),
-                lambda bh, j, tbl, ln, nh=n_q, g=group: (
-                    bh // nh, jnp.maximum(tbl[bh // nh, j], 0), 0,
-                    (bh % nh) // g, 0)),
+            pl.BlockSpec((1, n_q, d), per_request),
+            pl.BlockSpec((1, 1, rows, d), page_map),
+            pl.BlockSpec((1, 1, rows, d), page_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, d), lambda bh, j, tbl, ln, nh=n_q: (bh // nh, bh % nh, 0)),
-            pl.BlockSpec((1, 1, n_active),
-                         lambda bh, j, tbl, ln, nh=n_q: (bh // nh, bh % nh, 0)),
+            pl.BlockSpec((1, n_q, d), per_request),
+            pl.BlockSpec((1, n_q, n_active), per_request),
         ],
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, n_active), jnp.float32),
+            pltpu.VMEM((n_q, 1), jnp.float32),
+            pltpu.VMEM((n_q, 1), jnp.float32),
+            pltpu.VMEM((n_q, d), jnp.float32),
+            pltpu.VMEM((n_q, n_active), jnp.float32),
         ],
     )
     out, mass = pl.pallas_call(
@@ -136,5 +150,5 @@ def decode_attention(
             jax.ShapeDtypeStruct((b, n_q, n_active), jnp.float32),
         ],
         interpret=interpret,
-    )(page_table, lengths, q, k_pool, v_pool)
+    )(page_table, lengths, q, k_tiles, v_tiles)
     return out, mass

@@ -6,12 +6,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import _default_interpret
 from repro.kernels.decode_attention.kernel import decode_attention as _kernel
 from repro.kernels.decode_attention.ref import decode_attention_ref
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @jax.jit

@@ -5,12 +5,9 @@ from functools import partial
 
 import jax
 
+from repro.kernels import _default_interpret
 from repro.kernels.flash_attention.kernel import flash_attention as _kernel
 from repro.kernels.flash_attention.ref import flash_attention_ref
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "q_offset", "block_q",

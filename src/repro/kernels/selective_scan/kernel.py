@@ -3,12 +3,22 @@
 The XLA-level chunked `associative_scan` materializes (b, chunk, d_in, n)
 state-expansion tensors in HBM every level — the dominant memory term of the
 falcon-mamba train cells (§Perf B). This kernel keeps the recurrent state
-(d_block, n) resident in VMEM across the whole sequence: HBM traffic drops to
-reading x/dt/B/C tiles once and writing y once.
+resident in VMEM across the whole sequence: HBM traffic drops to reading
+x/dt/B/C tiles once and writing y once.
 
 Grid = (b, d_in_blocks, s_blocks); the sequence axis is innermost and
 sequential, so the VMEM scratch carries h across s-blocks; within a block a
 fori_loop steps the recurrence on VMEM-resident tiles.
+
+TPU layout: the state is kept transposed, ``(n, d_blk)``, so the channel
+axis fills the 128 lanes (a small ``n`` on the lanes would pad every vreg
+8x).  Step ``t``'s row of ``x`` is read from the ref with ``pl.ds`` and its
+row of ``y`` written the same way; ``dt`` is a scalar read from SMEM; ``B``
+and ``C`` arrive with the sequence on the lanes, and step ``t``'s column is
+picked out with a one-hot lane mask and a lane reduction, so the loop body
+never indexes a loaded value with the traced step.  The wrapper transposes
+``A``, ``B``, ``C``, ``h0`` and the final state, so callers keep the
+``(d_in, n)`` convention.
 """
 from __future__ import annotations
 
@@ -26,26 +36,26 @@ def _scan_kernel(x_ref, dt_ref, B_ref, C_ref, A_ref, h0_ref, y_ref, hout_ref,
 
     @pl.when(si == 0)
     def _init():
-        h_scr[...] = h0_ref[0].astype(jnp.float32)
+        h_scr[...] = h0_ref[0]
 
-    x = x_ref[0].astype(jnp.float32)  # (block_s, d_blk)
-    dt = dt_ref[0].astype(jnp.float32)  # (block_s, 1)
-    Bm = B_ref[0].astype(jnp.float32)  # (block_s, n)
-    Cm = C_ref[0].astype(jnp.float32)
-    A = A_ref[...].astype(jnp.float32)  # (d_blk, n)
+    Bt = B_ref[0]  # (n, block_s)
+    Ct = C_ref[0]
+    At = A_ref[...]  # (n, d_blk)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, block_s), 1)
 
-    def step(t, carry):
-        h = carry
-        dA = jnp.exp(dt[t, 0] * A)  # (d_blk, n)
-        dBx = (dt[t, 0] * x[t])[:, None] * Bm[t][None, :]
-        h = dA * h + dBx
-        y_t = jax.lax.dot_general(h, Cm[t][:, None], (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)[:, 0]
-        y_ref[0, t, :] = y_t.astype(y_ref.dtype)
+    def column(tile, hot):  # (rows, block_s) -> (rows, 1): column t, exactly
+        return jnp.sum(jnp.where(hot, tile, 0.0), axis=1, keepdims=True)
+
+    def step(t, h):  # h: (n, d_blk)
+        hot = lane == t
+        dt_t = dt_ref[0, 0, t]  # scalar from SMEM
+        x_t = x_ref[0, pl.ds(t, 1), :]  # (1, d_blk)
+        h = jnp.exp(dt_t * At) * h + column(Bt, hot) * (dt_t * x_t)
+        y_t = jnp.sum(column(Ct, hot) * h, axis=0, keepdims=True)
+        y_ref[0, pl.ds(t, 1), :] = y_t
         return h
 
-    h = jax.lax.fori_loop(0, block_s, step, h_scr[...])
-    h_scr[...] = h
+    h_scr[...] = jax.lax.fori_loop(0, block_s, step, h_scr[...])
 
     @pl.when(si == n_s_blocks - 1)
     def _done():
@@ -75,8 +85,9 @@ def selective_scan(
     assert s % block_s == 0 and d_in % block_d == 0
     n_s = s // block_s
     n_d = d_in // block_d
+    f32 = jnp.float32
     if h0 is None:
-        h0 = jnp.zeros((b, d_in, n), jnp.float32)
+        h0 = jnp.zeros((b, d_in, n), f32)
 
     kernel = functools.partial(_scan_kernel, block_s=block_s, n_s_blocks=n_s)
     y, h_final = pl.pallas_call(
@@ -84,21 +95,24 @@ def selective_scan(
         grid=(b, n_d, n_s),
         in_specs=[
             pl.BlockSpec((1, block_s, block_d), lambda bi, di, si: (bi, si, di)),
-            pl.BlockSpec((1, block_s, 1), lambda bi, di, si: (bi, si, 0)),
-            pl.BlockSpec((1, block_s, n), lambda bi, di, si: (bi, si, 0)),
-            pl.BlockSpec((1, block_s, n), lambda bi, di, si: (bi, si, 0)),
-            pl.BlockSpec((block_d, n), lambda bi, di, si: (di, 0)),
-            pl.BlockSpec((1, block_d, n), lambda bi, di, si: (bi, di, 0)),
+            pl.BlockSpec((1, 1, block_s), lambda bi, di, si: (bi, 0, si),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, n, block_s), lambda bi, di, si: (bi, 0, si)),
+            pl.BlockSpec((1, n, block_s), lambda bi, di, si: (bi, 0, si)),
+            pl.BlockSpec((n, block_d), lambda bi, di, si: (0, di)),
+            pl.BlockSpec((1, n, block_d), lambda bi, di, si: (bi, 0, di)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_s, block_d), lambda bi, di, si: (bi, si, di)),
-            pl.BlockSpec((1, block_d, n), lambda bi, di, si: (bi, di, 0)),
+            pl.BlockSpec((1, n, block_d), lambda bi, di, si: (bi, 0, di)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, s, d_in), jnp.float32),
-            jax.ShapeDtypeStruct((b, d_in, n), jnp.float32),
+            jax.ShapeDtypeStruct((b, s, d_in), f32),
+            jax.ShapeDtypeStruct((b, n, d_in), f32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_d, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, block_d), f32)],
         interpret=interpret,
-    )(x, dt[..., None], B, C, A, h0)
-    return y, h_final
+    )(x.astype(f32), dt.astype(f32)[:, None, :],
+      B.astype(f32).transpose(0, 2, 1), C.astype(f32).transpose(0, 2, 1),
+      A.astype(f32).T, h0.astype(f32).transpose(0, 2, 1))
+    return y, h_final.transpose(0, 2, 1)

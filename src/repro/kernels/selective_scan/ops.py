@@ -5,12 +5,9 @@ from functools import partial
 
 import jax
 
+from repro.kernels import _default_interpret
 from repro.kernels.selective_scan.kernel import selective_scan as _kernel
 from repro.kernels.selective_scan.ref import selective_scan_ref
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @partial(jax.jit, static_argnames=("block_s", "block_d", "use_kernel"))

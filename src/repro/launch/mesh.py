@@ -6,16 +6,8 @@ import jax
 
 
 def _axis_type_kwargs(n_axes: int) -> dict:
-    """`axis_types=` kwargs for `jax.make_mesh`, feature-detected.
-
-    `jax.sharding.AxisType` only exists on newer jax; older versions (which
-    default every axis to what newer jax calls Auto) must not see the kwarg
-    at all.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+    """`axis_types=` kwargs for `jax.make_mesh`: every axis Auto."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False, kv_split: int = 0):

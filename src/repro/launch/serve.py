@@ -1,11 +1,16 @@
 """Serving driver: concurrent request streams through the step-plan scheduler.
 
-Real mode (default) ingests a shared prefix into a tiny real model once, then
-serves a stream of requests concurrently — plans cooperatively multiplex over
-the thread-pool I/O, so one request's chunk reads overlap another's compute:
+Real mode (default) ingests a shared ``--prefix-len``-token prefix into the
+file-backed chunk store once, then serves a stream of requests concurrently —
+plans cooperatively multiplex over the thread-pool I/O, so one request's chunk
+reads overlap another's compute.  The model keeps its published widths with
+the depth cut to ``--n-layers`` (qwen2.5-7b at 8 layers fits one 16 GB v5e
+chip); ``--reduced`` swaps in the tiny same-family config for CPU runs:
 
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-14b \
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-7b \
       --system contiguous_kv --budget 0.25 --requests 8 --concurrency 4
+  PYTHONPATH=src JAX_PLATFORMS=cpu python -m repro.launch.serve --reduced \
+      --prefix-len 512 --requests 6 --concurrency 3
 
 Sim mode runs paper-scale multi-tenant serving on the calibrated
 discrete-event channels with Poisson/burst arrivals and prints the
@@ -59,7 +64,7 @@ decode bytes over a recurrent StatePool instead of a growing KV tail).  Every
 op's weight stream is namespaced per model, so iterations interleave across
 families but a batch never amortizes one model's weights against another's.
 Sim mode composes with ``--replicas``/``--disaggregate``; real mode builds
-one tiny real backend per tenant model (``--fleet`` with real-mode
+one real backend per tenant model (``--fleet`` with real-mode
 ``--replicas``/``--disaggregate`` is rejected — per-model worker backends
 are not wired yet).
 
@@ -76,6 +81,9 @@ per-tier hit counts, SSD log read amplification and the units dedup saved.
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
+import pathlib
 
 import numpy as np
 
@@ -152,25 +160,69 @@ def _print_handoff_digest(sched):
     print()
 
 
-def _real_main(args):
+def use_compile_cache() -> None:
+    """Keep JAX's persistent compilation cache where the caller can find it.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and wins;
+    otherwise the cache lives in ``.jax_cache/`` at the checkout root, a
+    fixed path (the path is part of every cache key, so a directory that
+    moved between runs would never hit).  Called from entry points, never
+    at import."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
     import jax
 
-    from repro.configs import reduced_config
+    root = pathlib.Path(__file__).resolve().parents[3]
+    jax.config.update("jax_compilation_cache_dir", str(root / ".jax_cache"))
+
+
+def real_config(name: str, args):
+    """The model a real-mode run serves: the published widths with only the
+    depth cut to ``--n-layers``, or, with ``--reduced``, the tiny
+    same-family config the CPU tests use."""
+    from repro.configs import get_config, reduced_config
+
+    if args.reduced:
+        return reduced_config(name, n_layers=args.n_layers)
+    return dataclasses.replace(get_config(name), n_layers=args.n_layers)
+
+
+@dataclasses.dataclass
+class RealRun:
+    """What one real-mode serving run leaves behind for a calling script:
+    the served requests, plus the engine (its backend, session and
+    file-backed prefix store stay usable) and the scheduler."""
+
+    cfg: object
+    task: object
+    engine: object
+    sched: Scheduler
+    completed: list
+
+
+def serve_real(args) -> RealRun:
+    """Real mode: ingest a ``--prefix-len``-token shared prefix into the
+    file-backed chunk store, then serve ``--requests`` requests through the
+    Scheduler; prints the per-request lines and the digest."""
+    import jax
+
     from repro.core import build_real_session
     from repro.core.backends import RealCompute
     from repro.data.synthetic import make_task
     from repro.models import transformer as T
     from repro.storage.timing import RealExecutor
 
-    cfg = reduced_config(args.arch, n_layers=args.n_layers)
+    cfg = real_config(args.arch, args)
     params = T.init_params(jax.random.PRNGKey(0), cfg)
-    task = make_task(args.dataset, cfg.vocab_size, n_queries=args.requests)
+    task = make_task(args.dataset, cfg.vocab_size, n_queries=args.requests,
+                     prefix_len=args.prefix_len)
     print(f"ingesting shared prefix: {len(task.prefix)} tokens "
-          f"({args.dataset}, {cfg.name})")
+          f"({args.dataset}, {cfg.name}: {cfg.n_layers} layers, d_model "
+          f"{cfg.d_model}, vocab {cfg.vocab_size})")
     coarse = args.system != "contiguous_kv"
     sess = build_real_session(cfg, params, task.prefix,
                               chunk_tokens=args.chunk_tokens,
-                              coarse_blocks=coarse, in_memory=True)
+                              coarse_blocks=coarse)
     ex = RealExecutor()
     from repro.core.hybrid import HybridPlanner
 
@@ -282,14 +334,15 @@ def _real_main(args):
         # with decode, c.result is the *last* token's logits, not the label
         print(f"label-token accuracy (untrained model => chance-level): "
               f"{correct}/{len(task.queries)}")
+    return RealRun(cfg=cfg, task=task, engine=eng, sched=sched,
+                   completed=completed)
 
 
 def _real_fleet_main(args):
-    """Real-mode heterogeneous fleet: one tiny real backend per tenant model,
+    """Real-mode heterogeneous fleet: one real backend per tenant model,
     every family iteration-batched behind the one wall-clock Scheduler."""
     import jax
 
-    from repro.configs import reduced_config
     from repro.core import build_real_session
     from repro.core.backends import RealCompute, StateCompute
     from repro.core.engine import StateSpaceEngine
@@ -301,21 +354,19 @@ def _real_fleet_main(args):
         raise SystemExit("--fleet in real mode does not compose with "
                          "--disaggregate/--replicas/--tp-decode (per-model "
                          "worker backends are not wired); use --mode sim")
-    entries = parse_fleet_spec(args.fleet)
+    entries = [(real_config(name, args), count)
+               for name, count in parse_fleet_spec(args.fleet)]
+    # one synthetic task over the smallest vocab, so every model of the
+    # fleet serves the same prompt/query stream
+    task = make_task(args.dataset, min(c.vocab_size for c, _ in entries),
+                     n_queries=args.requests, prefix_len=args.prefix_len)
+    print(f"ingesting shared prefix: {len(task.prefix)} tokens "
+          f"({args.dataset})")
     ex = RealExecutor()
     engines, cfgs = {}, {}
     tenant = 0
-    task = None
-    for name, count in entries:
-        cfg = reduced_config(name)
+    for cfg, count in entries:
         params = T.init_params(jax.random.PRNGKey(0), cfg)
-        if task is None:
-            # one synthetic task: every reduced config shares a vocab, so
-            # the fleet serves the same prompt/query stream
-            task = make_task(args.dataset, cfg.vocab_size,
-                             n_queries=args.requests)
-            print(f"ingesting shared prefix: {len(task.prefix)} tokens "
-                  f"({args.dataset})")
         for _ in range(count):
             tenant += 1
             cfgs[tenant] = cfg
@@ -328,10 +379,8 @@ def _real_fleet_main(args):
             coarse = args.system != "contiguous_kv"
             sess = build_real_session(cfg, params, task.prefix,
                                       chunk_tokens=args.chunk_tokens,
-                                      coarse_blocks=coarse, in_memory=True)
-            import dataclasses as _dc
-
-            sess = _dc.replace(sess, tenant=tenant)
+                                      coarse_blocks=coarse)
+            sess = dataclasses.replace(sess, tenant=tenant)
             kw = dict(device_cap=64, host_cap=128,
                       prefill_chunk_tokens=args.prefill_chunk_tokens,
                       device_tail_pool=not args.host_tail_pool)
@@ -475,7 +524,8 @@ def _sim_main(args):
                   f"host={u['host']}{ssd} units")
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
+    """The serving CLI's options; ``argv=None`` reads ``sys.argv``."""
     p = argparse.ArgumentParser()
     p.add_argument("--mode", default="real", choices=("real", "sim"))
     p.add_argument("--system", default="contiguous_kv", choices=list(ENGINE_CLASSES))
@@ -531,13 +581,21 @@ def main():
                         "the mesh GQA-style into (kv=K, rep=n/K), K=0 uses "
                         "one flat tensor axis")
     # real mode
-    p.add_argument("--arch", default="qwen2.5-14b")
+    p.add_argument("--arch", default="qwen2.5-7b")
     p.add_argument("--dataset", default="rte")
-    p.add_argument("--n-layers", type=int, default=4)
+    p.add_argument("--n-layers", type=int, default=8,
+                   help="real mode: depth the model is cut to (its widths "
+                        "stay the published ones unless --reduced)")
+    p.add_argument("--reduced", action="store_true",
+                   help="real mode: serve the tiny same-family config "
+                        "(d_model 64, vocab 256) the CPU tests use instead "
+                        "of the published widths")
+    p.add_argument("--prefix-len", type=int, default=4096,
+                   help="shared prefix tokens (real: drawn from --dataset's "
+                        "few-shot stream; sim: the modelled prefix length)")
     # sim mode
     p.add_argument("--model", default="qwen2.5-7b")
     p.add_argument("--tenants", type=int, default=1)
-    p.add_argument("--prefix-len", type=int, default=4096)
     p.add_argument("--rate", type=float, default=16.0, help="offered load, req/s")
     p.add_argument("--arrival", default="poisson",
                    choices=("poisson", "burst", "uniform"))
@@ -559,17 +617,23 @@ def main():
                    help="sim: the first K tenants serve one identical system "
                         "prompt (one content digest; with --cache-tiers it "
                         "dedupes to a single resident copy)")
-    args = p.parse_args()
+    args = p.parse_args(argv)
     if args.tenants < 1:
         p.error("--tenants must be >= 1")
     if args.concurrency < 1:
         p.error("--concurrency must be >= 1")
+    return args
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    use_compile_cache()
     if args.mode == "sim":
         _sim_main(args)
     elif args.fleet:
         _real_fleet_main(args)
     else:
-        _real_main(args)
+        serve_real(args)
 
 
 if __name__ == "__main__":

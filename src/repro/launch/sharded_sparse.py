@@ -32,16 +32,9 @@ NEG_INF = -1e30
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-compat shard_map: top-level `jax.shard_map` (new jax, with
-    `check_vma`) or `jax.experimental.shard_map.shard_map` (older jax,
-    where the same knob is called `check_rep`)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-
-    return legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False)
+    """`jax.shard_map` without the varying-manual-axes check."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _local_sparse_attention(q, k_shard, v_shard, kmean_shard, k_new, v_new,

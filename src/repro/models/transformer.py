@@ -13,6 +13,7 @@ Families:
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -73,7 +74,11 @@ def _init_layer(key, cfg: ModelConfig, dtype) -> Params:
     return p
 
 
+@partial(jax.jit, static_argnums=(1,))
 def init_params(key, cfg: ModelConfig) -> Params:
+    """Random weights from ``key``.  Jitted so every f32 draw fuses into its
+    cast: a published-width embedding drawn eagerly in f32 would hold a
+    multi-GB transient on the device before the cast."""
     dtype = cfg.activation_dtype()
     k_emb, k_layers, k_out = jax.random.split(key, 3)
     layer_keys = jax.random.split(k_layers, cfg.n_layers)

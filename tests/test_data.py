@@ -49,3 +49,23 @@ def test_stream_is_next_token_prediction():
     b1 = next(stream)
     # within one document chunk, labels[i] == tokens[i+1]
     np.testing.assert_array_equal(b1["tokens"][:, 1:], b1["labels"][:, :-1])
+
+
+def test_task_same_in_every_process():
+    """The served prefix must not depend on the per-process str-hash salt."""
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    code = ("from repro.data.synthetic import make_task; "
+            "t = make_task('rte', 1000, n_queries=2, prefix_len=300); "
+            "print(t.prefix.tolist(), [q.tolist() for q, _ in t.queries])")
+    outs = []
+    for salt in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=salt, PYTHONPATH=src)
+        outs.append(subprocess.run([sys.executable, "-c", code], env=env,
+                                   capture_output=True, text=True,
+                                   check=True, timeout=120).stdout)
+    assert outs[0] == outs[1]
+    assert len(make_task("rte", 1000, prefix_len=300).prefix) == 300

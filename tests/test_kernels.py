@@ -347,3 +347,18 @@ class TestSelectiveScan:
                                    rtol=1e-5, atol=1e-5)
         np.testing.assert_allclose(np.asarray(h_pad), np.asarray(h_ex),
                                    rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("backend,expect", [("cpu", True), ("tpu", False),
+                                            ("gpu", None)])
+def test_default_interpret_only_on_cpu(monkeypatch, backend, expect):
+    """Kernels compile on the TPU, interpret on the CPU, and refuse any
+    other backend instead of silently interpreting there."""
+    from repro.kernels import _default_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if expect is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            _default_interpret()
+    else:
+        assert _default_interpret() is expect

@@ -173,3 +173,37 @@ class TestTenancy:
         ]
         picked = policy.select(queued, fleet.engines)
         assert picked.request_id == 2
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_placement(monkeypatch, tmp_path, env_dir):
+    """Unset JAX_COMPILATION_CACHE_DIR: the cache goes to <checkout>/.jax_cache;
+    set: the code leaves JAX's own setting alone."""
+    import pathlib
+
+    import jax
+
+    from repro.launch.serve import use_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / env_dir))
+    try:
+        use_compile_cache()
+        got = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert got == (str(root / ".jax_cache") if env_dir is None else before)
+
+
+def test_real_config_keeps_published_widths():
+    from repro.launch.serve import parse_args, real_config
+
+    cfg = real_config("qwen2.5-7b", parse_args(["--n-layers", "8"]))
+    assert (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
+            cfg.d_ff, cfg.vocab_size) == (8, 3584, 28, 4, 18944, 152064)
+    tiny = real_config("qwen2.5-7b", parse_args(["--reduced", "--n-layers", "2"]))
+    assert (tiny.n_layers, tiny.d_model, tiny.vocab_size) == (2, 64, 256)
