@@ -9,6 +9,7 @@ SimCompute  — returns placeholders; selection comes from a workload model;
 from __future__ import annotations
 
 import dataclasses
+import functools
 from functools import partial
 from typing import Dict, Optional, Tuple
 
@@ -16,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import sparse_attention as SA
 from repro.kernels.decode_attention.ops import (
     decode_attention,
@@ -192,14 +194,15 @@ class TailPool:
         tbl[: self.n_active] = np.arange(self.n_active, dtype=np.int32)
         return tbl
 
-    def attend_args(self):
-        """(k_pool, v_pool, table, lengths) for a b=1 decode_attention call.
+    def attend_args(self, put=None):
+        """(k_pool, v_pool, table, lengths) for a b=1 decode_attention call;
+        `put` uploads a host array (default ``jnp.asarray``).
 
         Host pool: the full fixed-size buffer is uploaded on every call —
         exactly the per-step H2D traffic the device pool eliminates."""
-        return (jnp.asarray(self.k)[None], jnp.asarray(self.v)[None],
-                jnp.asarray(self.table())[None],
-                jnp.asarray(np.array([self.valid_tokens], np.int32)))
+        put = put or jnp.asarray
+        return (put(self.k[None]), put(self.v[None]), put(self.table()[None]),
+                put(np.array([self.valid_tokens], np.int32)))
 
     def swap_out(self) -> int:
         """Snapshot the pool to host memory; returns bytes moved over PCIe.
@@ -295,24 +298,26 @@ class DeviceTailPool(TailPool):
         p, s = divmod(self.t, self.page)
         return self.n_res + p, s
 
-    def device_table(self):
-        """Device page table (1, width), re-uploaded only when a page
-        boundary crossing changes ``n_active`` (log-many tiny uploads per
-        decode, not per step)."""
+    def device_table(self, put=None):
+        """Device page table (1, width), re-uploaded (through `put`,
+        default ``jax.device_put``) only when a page boundary crossing
+        changes ``n_active`` (log-many tiny uploads per decode, not per
+        step)."""
         if self._tbl_n != self.n_active:
             self._tbl_n = self.n_active
-            self._tbl_dev = jax.device_put(self.table()[None])
+            self._tbl_dev = (put or jax.device_put)(self.table()[None])
         return self._tbl_dev
 
-    def attend_args(self):
+    def attend_args(self, put=None):
         """(k_pool, v_pool, table, lengths) with zero pool H2D traffic.
 
         The batch dims on k/v are added eagerly here for interface parity
         with the host pool; the hot path (``RealCompute.decode_attend``)
         instead hands the raw buffers to ``decode_attention_pools`` so the
         expand + b=1 stack trace into the jitted step."""
-        return (self.k[None], self.v[None], self.device_table(),
-                jnp.asarray(np.array([self.valid_tokens], np.int32)))
+        put = put or jnp.asarray
+        return (self.k[None], self.v[None], self.device_table(put),
+                put(np.array([self.valid_tokens], np.int32)))
 
     def swap_out(self) -> int:
         assert self.is_resident, "pool already swapped out"
@@ -424,6 +429,18 @@ class StatePool:
         return nbytes if self.is_device else 0
 
 
+def _step_span(method):
+    """Run a :class:`RealCompute` method inside a ``step.<name>`` span."""
+    name = "step." + method.__name__
+
+    @functools.wraps(method)
+    def spanned(*args, **kw):
+        with obs.span(name):
+            return method(*args, **kw)
+
+    return spanned
+
+
 class RealCompute:
     """Tiny-model execution; batch = 1 request.
 
@@ -433,13 +450,22 @@ class RealCompute:
     pages shard over the mesh's tensor axes with per-shard page tables, and
     both ``decode_attend`` and ``decode_step_batch`` route through it.  The
     sharded attend is a drop-in (same signature, same per-page mass
-    contract) validated bit-close to the single-device kernel."""
+    contract) validated bit-close to the single-device kernel.
+
+    Counters: ``d2h_syncs`` counts the blocking device-to-host reads of
+    device results (every one goes through :meth:`_to_host`) and
+    ``h2d_bytes`` the bytes of host arrays uploaded by these methods (every
+    one goes through :meth:`_to_device`).  Each method runs inside a
+    ``step.<method>`` span, each read inside ``step.d2h`` and each upload
+    inside ``step.h2d`` (:mod:`repro.obs`)."""
 
     def __init__(self, cfg: ModelConfig, params, *, tp_mesh=None):
         assert cfg.has_attention, "Re-Prefill engine needs attention KV"
         self.cfg = cfg
         self.params = params
         self.tp_mesh = tp_mesh
+        self.d2h_syncs = 0
+        self.h2d_bytes = 0
         if tp_mesh is not None:
             # lazy import: core must stay importable without launch/
             from repro.launch.sharded_sparse import make_sharded_paged_decode
@@ -448,39 +474,65 @@ class RealCompute:
         else:
             self._tp_attend = None
 
-    def embed(self, suffix_tokens: np.ndarray):
-        return _embed(self.params, jnp.asarray(suffix_tokens)[None], self.cfg)
+    def _to_host(self, x, dtype=None) -> np.ndarray:
+        """Blocking read of a device result into host memory (counted)."""
+        self.d2h_syncs += 1
+        with obs.span("step.d2h"):
+            return np.asarray(x, dtype)
 
+    def _to_device(self, x):
+        """Upload a host array (counted; device arrays pass through).  Goes
+        through ``jnp.asarray``, so ``storage.h2d_meter.H2DMeter`` sees the
+        same bytes."""
+        if isinstance(x, jax.Array):
+            return x
+        x = np.asarray(x)
+        self.h2d_bytes += x.nbytes
+        with obs.span("step.h2d"):
+            return jnp.asarray(x)
+
+    @_step_span
+    def embed(self, suffix_tokens: np.ndarray):
+        toks = self._to_device(np.asarray(suffix_tokens)[None])
+        return _embed(self.params, toks, self.cfg)
+
+    @_step_span
     def part_a(self, layer: int, h, prefix_len: int):
         lp = _slice_layer(self.params, layer)
         return _part_a(lp, h, self.cfg, int(prefix_len))
 
+    @_step_span
     def part_a_at(self, layer: int, h, positions):
         """part_a with traced (b, s) positions: decode steps advance their
         position every token, so a static-offset jit would retrace per step."""
+        if not isinstance(positions, jax.Array):
+            positions = np.asarray(positions, np.int32)
         lp = _slice_layer(self.params, layer)
-        return _part_a_at(lp, h, self.cfg, jnp.asarray(positions, jnp.int32))
+        return _part_a_at(lp, h, self.cfg, self._to_device(positions))
 
+    @_step_span
     def token_scores(self, q, k_probe: np.ndarray, layer: int) -> np.ndarray:
         """q: (1, s, nq, d) device; k_probe: (n, n_kv, d_probe) numpy."""
         d = self.cfg.d_head
-        kp = jnp.asarray(k_probe)
+        kp = self._to_device(k_probe)
         qq = q[0]
         if kp.shape[-1] != d:  # partial keys (IMPRESS): truncate q dims to match
             qq = qq[..., : kp.shape[-1]]
-        return np.asarray(SA.probe_token_scores(qq, kp))
+        return self._to_host(SA.probe_token_scores(qq, kp))
 
+    @_step_span
     def part_b(self, layer: int, h, q, k_suf, v_suf,
                k_sel: np.ndarray, v_sel: np.ndarray, sel_valid: np.ndarray,
                chunk_tokens: int):
         lp = _slice_layer(self.params, layer)
+        put = self._to_device
         h, mass = _part_b(
-            lp, h, q, k_suf, v_suf,
-            jnp.asarray(k_sel), jnp.asarray(v_sel), jnp.asarray(sel_valid),
+            lp, h, q, k_suf, v_suf, put(k_sel), put(v_sel), put(sel_valid),
             self.cfg, chunk_tokens,
         )
-        return h, np.asarray(mass)
+        return h, self._to_host(mass)
 
+    @_step_span
     def part_b_batch(self, ctxs):
         """b plans' same-layer final prefill chunks as one vmapped pass.
 
@@ -495,15 +547,17 @@ class RealCompute:
         q = jnp.stack([c.q for c in ctxs])
         k_suf = jnp.stack([c.k_suf for c in ctxs])
         v_suf = jnp.stack([c.v_suf for c in ctxs])
-        k_sel = jnp.asarray(np.stack([np.asarray(c.k_sel) for c in ctxs]))
-        v_sel = jnp.asarray(np.stack([np.asarray(c.v_sel) for c in ctxs]))
-        valid = jnp.asarray(np.stack([np.asarray(c.valid) for c in ctxs]))
+        put = self._to_device
+        k_sel = put(np.stack([np.asarray(c.k_sel) for c in ctxs]))
+        v_sel = put(np.stack([np.asarray(c.v_sel) for c in ctxs]))
+        valid = put(np.stack([np.asarray(c.valid) for c in ctxs]))
         hs, masses = _part_b_batch_kernel(lp, h, q, k_suf, v_suf, k_sel,
                                           v_sel, valid, self.cfg,
                                           c0.chunk_tokens)
-        mass_host = np.asarray(masses)
+        mass_host = self._to_host(masses)
         return [(hs[i], mass_host[i]) for i in range(len(ctxs))]
 
+    @_step_span
     def recompute_prefix_kv(self, prefix_tokens: np.ndarray, end: int,
                             block_q: int):
         """Recompute KV for the prefix head ``[0, end)`` from raw tokens.
@@ -512,22 +566,23 @@ class RealCompute:
         (``transformer.forward`` with ``return_kv=True``), so the fp16 KV is
         *bit-identical* to what ``ChunkStore`` holds: causal attention over
         a head never sees the tail, and the NEG_INF mask zeroes excluded
-        positions exactly.  The token upload goes through ``jnp.asarray`` so
-        the H2D meter accounts it.  Returns (k, v), each (L, end, n_kv, d)
-        float16.
+        positions exactly.  Returns (k, v), each (L, end, n_kv, d) float16.
         """
         from repro.models import transformer as T
 
-        toks = jnp.asarray(np.asarray(prefix_tokens[:end]))[None]
+        toks = self._to_device(np.asarray(prefix_tokens[:end])[None])
         _, kvs = T.forward(self.params, {"tokens": toks}, self.cfg,
                            block_q=block_q, return_kv=True)
-        k = np.asarray(kvs[0][:, 0], np.float16)
-        v = np.asarray(kvs[1][:, 0], np.float16)
+        k = self._to_host(kvs[0][:, 0], np.float16)
+        v = self._to_host(kvs[1][:, 0], np.float16)
         return k, v
 
+    @_step_span
     def logits(self, h) -> np.ndarray:
-        return np.asarray(_final_logits_kernel(self.params, h, self.cfg.norm_eps))
+        return self._to_host(
+            _final_logits_kernel(self.params, h, self.cfg.norm_eps))
 
+    @_step_span
     def decode_attend(self, layer: int, h, q, tail: TailPool):
         """One decode position's sparse attention over `tail`'s paged pool.
 
@@ -544,24 +599,24 @@ class RealCompute:
         cfg = self.cfg
         lp = _slice_layer(self.params, layer)
         q1 = q[:, 0]  # (1, n_q, d) — single decode position
+        put = self._to_device
+        lengths = np.array([tail.valid_tokens], np.int32)
         if self._tp_attend is not None:
             if tail.is_device:
                 k_pool, v_pool = stack_pool_buffers((tail.k,), (tail.v,))
                 out, page_mass = self._tp_attend(
-                    q1, k_pool, v_pool, tail.device_table(),
-                    jnp.asarray(np.array([tail.valid_tokens], np.int32)))
+                    q1, k_pool, v_pool, tail.device_table(put), put(lengths))
             else:
-                out, page_mass = self._tp_attend(q1, *tail.attend_args())
+                out, page_mass = self._tp_attend(q1, *tail.attend_args(put))
         elif tail.is_device:
             # raw device buffers straight into the jitted step: the b=1
             # expand happens inside the trace, so the whole attend is one
-            # dispatch with zero pool bytes moved (lengths goes through
-            # jnp.asarray so the H2D meter sees every host-sourced byte)
+            # dispatch with zero pool bytes moved
             out, page_mass = decode_attention_pools(
-                q1, (tail.k,), (tail.v,), tail.device_table(),
-                jnp.asarray(np.array([tail.valid_tokens], np.int32)))
+                q1, (tail.k,), (tail.v,), tail.device_table(put),
+                put(lengths))
         else:
-            out, page_mass = decode_attention(q1, *tail.attend_args())
+            out, page_mass = decode_attention(q1, *tail.attend_args(put))
         attn = out.reshape(1, 1, cfg.n_heads, cfg.d_head)
         o = jnp.einsum("bshk,hkd->bsd", attn, lp["wo"])
         h = h + o
@@ -569,8 +624,9 @@ class RealCompute:
         # per-resident-page attention mass (decode-time cache scores) comes
         # straight from the kernel's online softmax — no second score pass
         mass = page_mass[0].mean(axis=0)[: tail.n_res]  # head-avg, resident
-        return h, np.asarray(mass)
+        return h, self._to_host(mass)
 
+    @_step_span
     def decode_step_batch(self, ctxs):
         """One decode position for b requests in a single batched pass.
 
@@ -585,11 +641,11 @@ class RealCompute:
         expect from their single-request `fn`.
         """
         cfg = self.cfg
+        put = self._to_device
         b = len(ctxs)
         tokens = np.array([c.token for c in ctxs], np.int64)[:, None]
-        h = _embed(self.params, jax.device_put(tokens), cfg)  # (b, 1, d_model)
-        positions = jax.device_put(
-            np.array([[c.pos] for c in ctxs], np.int32))
+        h = _embed(self.params, put(tokens), cfg)  # (b, 1, d_model)
+        positions = put(np.array([[c.pos] for c in ctxs], np.int32))
         device = ctxs[0].pools[0].is_device
         masses = [{} for _ in ctxs]
         for l in range(cfg.n_layers):
@@ -602,10 +658,7 @@ class RealCompute:
                 pools_l = [c.pools[l] for c in ctxs]
                 for p in pools_l:
                     p._check_capacity(1)
-                # slots ride through jnp.asarray (not a raw jit argument)
-                # so the H2D meter accounts every host-sourced transfer
-                slots = jnp.asarray(
-                    np.array([p.slot() for p in pools_l], np.int32))
+                slots = put(np.array([p.slot() for p in pools_l], np.int32))
                 new_ks, new_vs, k_pool, v_pool = _pool_write_batch_device(
                     tuple(p.k for p in pools_l), tuple(p.v for p in pools_l),
                     k_cur, v_cur, slots)
@@ -617,26 +670,26 @@ class RealCompute:
                 lengths = np.array([p.valid_tokens for p in pools_l],
                                    np.int32)
             else:
-                k_host = np.asarray(k_cur)  # (b, 1, n_kv, d) — one transfer
-                v_host = np.asarray(v_cur)
+                k_host = self._to_host(k_cur)  # (b, 1, n_kv, d)
+                v_host = self._to_host(v_cur)
                 for i, c in enumerate(ctxs):
                     c.pools[l].append(k_host[i], v_host[i])
                 k_pool, v_pool, table, lengths = stack_tail_pools(
                     [c.pools[l] for c in ctxs])
-                k_pool, v_pool = jnp.asarray(k_pool), jnp.asarray(v_pool)
+                k_pool, v_pool = put(k_pool), put(v_pool)
             attend = (self._tp_attend if self._tp_attend is not None
                       else decode_attention)
-            out, page_mass = attend(
-                q[:, 0], k_pool, v_pool, jnp.asarray(table),
-                jnp.asarray(lengths))
+            out, page_mass = attend(q[:, 0], k_pool, v_pool, put(table),
+                                    put(lengths))
             attn = out.reshape(b, 1, cfg.n_heads, cfg.d_head)
             o = jnp.einsum("bshk,hkd->bsd", attn, lp["wo"])
             h = h + o
             h = _ffn(h, lp, cfg, dropless=True)
-            pm = np.asarray(page_mass)  # (b, n_q, width)
+            pm = self._to_host(page_mass)  # (b, n_q, width)
             for i, c in enumerate(ctxs):
                 masses[i][l] = pm[i].mean(axis=0)[: c.pools[l].n_res]
-        logits = np.asarray(_final_logits_kernel(self.params, h, cfg.norm_eps))
+        logits = self._to_host(
+            _final_logits_kernel(self.params, h, cfg.norm_eps))
         return [(logits[i: i + 1], masses[i]) for i in range(b)]
 
 

@@ -27,6 +27,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import costmodel as CM
 from repro.core.cache import (
     DEVICE,
@@ -92,6 +93,7 @@ class PrefixSession:
 @dataclasses.dataclass
 class ReprefillTrace:
     system: str = ""
+    request_id: int = 0
     ttft: float = 0.0
     stages: Dict[str, float] = dataclasses.field(default_factory=dict)
     ssd_bytes: int = 0  # all KV bytes read from SSD (demand + speculative)
@@ -118,6 +120,14 @@ class ReprefillTrace:
     recompute_tokens: int = 0  # causal frontier extent of the recompute leg
     ssd_bytes_avoided: int = 0  # SSD traffic (all layers) recompute saved
     hybrid_decision: object = None  # core.hybrid.HybridDecision (or None)
+    # kept by the Scheduler's real driver: seconds the plan waited on
+    # unfinished I/O before its first token (from the first poll that found
+    # a wait pending to the transfer's completion), and its share of the
+    # backend's blocking device-to-host reads and uploaded bytes (a batched
+    # step's counts split evenly over its members)
+    io_stall_s: float = 0.0
+    d2h_syncs: float = 0.0
+    h2d_bytes: float = 0.0
 
     @property
     def read_amplification(self) -> float:
@@ -224,7 +234,7 @@ class _EngineBase:
         (phase="decode") — sparse decode attention over the resident units.
         """
         clock = RequestClock(arrival)
-        trace = ReprefillTrace(system=self.name)
+        trace = ReprefillTrace(system=self.name, request_id=request_id)
         gen = self._steps(np.asarray(suffix_tokens), request_id, clock, trace,
                           decode_tokens=decode_tokens)
         return StepPlan(request_id=request_id, gen=gen, clock=clock, trace=trace)
@@ -373,7 +383,8 @@ class _EngineBase:
             for u in host_hits:
                 handles[self._key(layer, u)] = h
         if missing and not combined:
-            fetch = self._mk_fetch(layer, missing, from_host=False)
+            fetch = self._mk_fetch(layer, missing, from_host=False,
+                                   rid=trace.request_id, nbytes=miss_nb)
             if fetch is not None and self.hybrid is not None:
                 # feed the planner's EWMA of measured IO service time
                 fetch = self.hybrid.timed_fetch(fetch, miss_nb, miss_nr)
@@ -388,7 +399,8 @@ class _EngineBase:
                 handles[self._key(layer, u)] = h
         return None
 
-    def _mk_fetch(self, layer: int, units: List[int], from_host: bool):
+    def _mk_fetch(self, layer: int, units: List[int], from_host: bool,
+                  rid: int = 0, nbytes: int = 0):
         if self.sim:
             return None
         store = self.session.store
@@ -396,7 +408,8 @@ class _EngineBase:
         def fetch():
             if from_host:
                 return {int(u): self._unit_data(layer, int(u)) for u in units}
-            got = store.read_units(layer, units)
+            with obs.span("store.read", rid=rid, layer=layer, bytes=nbytes):
+                got = store.read_units(layer, units)
             for u, arr in got.items():
                 self._data[self._key(layer, u)] = arr
             return got
@@ -697,11 +710,12 @@ class _EngineBase:
             compute_dtype = next(
                 (np.dtype(kv[0].dtype) for kv in kv_suffix.values()), None)
             pool_cls = DeviceTailPool if self.device_tail_pool else TailPool
-            for l in range(cfg.n_layers):
-                k_res, v_res = self._gather_unit_pages(l, res_layers[l])
-                pools[l] = pool_cls(k_res, v_res, kv_suffix.get(l),
-                                    unit_tokens, decode_tokens,
-                                    dtype=compute_dtype)
+            with obs.span("eng.gather", rid=request_id):
+                for l in range(cfg.n_layers):
+                    k_res, v_res = self._gather_unit_pages(l, res_layers[l])
+                    pools[l] = pool_cls(k_res, v_res, kv_suffix.get(l),
+                                        unit_tokens, decode_tokens,
+                                        dtype=compute_dtype)
         for step in range(decode_tokens):
             if self.sim:
                 scores = be.decode_scores(request_id, step)
@@ -715,8 +729,9 @@ class _EngineBase:
                 per_layer = res_layers
             trace.decode_selected.append(per_layer[0])
             # demand-fetch cache misses, then wait on in-flight transfers
-            for l, units in per_layer.items():
-                self._submit_units(l, list(units), trace, handles, clock)
+            with obs.span("eng.submit", rid=request_id):
+                for l, units in per_layer.items():
+                    self._submit_units(l, list(units), trace, handles, clock)
             t0 = clock.t
             waited = set()
             for l, units in per_layer.items():
@@ -769,15 +784,18 @@ class _EngineBase:
                 logits, masses = out
                 tok = int(np.argmax(logits[0, -1]))
                 trace.decode_tokens_out.append(tok)
-            for l, units in per_layer.items():
-                if isinstance(self.cache, AttentionGuidedCache) and len(units):
-                    if masses is not None:
-                        m = np.asarray(masses[l])
-                    else:
-                        m = be.decode_mass(request_id, l, len(units))
-                    for i, u in enumerate(units):
-                        self.cache.update_importance(self._key(l, u), float(m[i]))
-                self._insert_cache(l, units)
+            with obs.span("eng.cache", rid=request_id):
+                for l, units in per_layer.items():
+                    if (isinstance(self.cache, AttentionGuidedCache)
+                            and len(units)):
+                        if masses is not None:
+                            m = np.asarray(masses[l])
+                        else:
+                            m = be.decode_mass(request_id, l, len(units))
+                        for i, u in enumerate(units):
+                            self.cache.update_importance(self._key(l, u),
+                                                         float(m[i]))
+                    self._insert_cache(l, units)
             trace.decode_times.append(clock.t)
         return logits
 
@@ -817,7 +835,11 @@ class ContiguousKVEngine(_EngineBase):
                             flops=2.0 * s * cfg.d_model, tag="compute")
         handles: Dict = {}
         probe_handles: Dict[int, IOHandle] = {}
-        probe_handles[0] = self._submit_probe(0, trace, clock)
+        # spans (repro.obs) open and close between two yields: other plans
+        # run while this one is suspended
+        rid = request_id
+        with obs.span("eng.submit", rid=rid):
+            probe_handles[0] = self._submit_probe(0, trace, clock)
 
         for period in self.schedule:
             head = period.head
@@ -826,7 +848,9 @@ class ContiguousKVEngine(_EngineBase):
                 flops=self._cost_part_a(s), tag="compute")
 
             if period.index not in probe_handles:  # lazy (no inter-period)
-                probe_handles[period.index] = self._submit_probe(head, trace, clock)
+                with obs.span("eng.submit", rid=rid):
+                    probe_handles[period.index] = self._submit_probe(
+                        head, trace, clock)
             t0 = clock.t
             probe_data = yield WaitOp(probe_handles[period.index], tag="probe_io")
             trace.add_stage("probe_io", clock.t - t0)
@@ -835,13 +859,12 @@ class ContiguousKVEngine(_EngineBase):
                 self._bound(request_id,
                             lambda qq=q, pd=probe_data, l=head: be.token_scores(qq, pd, l)),
                 flops=self._cost_identify(s), tag="identify")
-            cs = np.asarray(
-                np.add.reduceat(
-                    np.pad(tok_scores, (0, meta.n_chunks * meta.chunk_tokens - len(tok_scores))),
-                    np.arange(0, meta.n_chunks * meta.chunk_tokens, meta.chunk_tokens),
-                )
-            )
-            selected = select_topk_chunks(cs, self.budget)
+            with obs.span("eng.select", rid=rid):
+                n_tok = meta.n_chunks * meta.chunk_tokens
+                cs = np.asarray(np.add.reduceat(
+                    np.pad(tok_scores, (0, n_tok - len(tok_scores))),
+                    np.arange(0, n_tok, meta.chunk_tokens)))
+                selected = select_topk_chunks(cs, self.budget)
             trace.selected_per_period.append(selected)
             for l in period.layers:
                 trace.selected_per_layer[l] = selected
@@ -854,21 +877,28 @@ class ContiguousKVEngine(_EngineBase):
                     extra_overlap_flops=(len(self.schedule)
                                          * self._cost_identify(s)))
             if self.prefetch:
-                for l in period.layers:
-                    self._submit_units(l, list(selected), trace, handles, clock)
-                if self.inter_period and period.index + 1 < len(self.schedule):
-                    nxt = self.schedule.periods[period.index + 1]
-                    probe_handles[nxt.index] = self._submit_probe(nxt.head, trace, clock)
-                    for l in nxt.layers:  # speculative warm-up with current set
-                        self._submit_units(l, list(selected), trace, handles, clock,
-                                           speculative=True)
+                with obs.span("eng.submit", rid=rid):
+                    for l in period.layers:
+                        self._submit_units(l, list(selected), trace, handles,
+                                           clock)
+                    if (self.inter_period
+                            and period.index + 1 < len(self.schedule)):
+                        nxt = self.schedule.periods[period.index + 1]
+                        probe_handles[nxt.index] = self._submit_probe(
+                            nxt.head, trace, clock)
+                        for l in nxt.layers:  # speculative warm-up, current set
+                            self._submit_units(l, list(selected), trace,
+                                               handles, clock,
+                                               speculative=True)
                 for l in self.schedule.gate_layers(period):
                     yield from self._wait_keys(l, selected, handles, trace,
                                                "kv_io", clock)
             elif period.index + 1 < len(self.schedule):
                 nxt = self.schedule.periods[period.index + 1]
                 # probe must still be loaded for the next period (on demand)
-                probe_handles[nxt.index] = self._submit_probe(nxt.head, trace, clock)
+                with obs.span("eng.submit", rid=rid):
+                    probe_handles[nxt.index] = self._submit_probe(
+                        nxt.head, trace, clock)
 
             n_attended = len(selected) * meta.chunk_tokens + s
             for l in period.layers:
@@ -877,9 +907,13 @@ class ContiguousKVEngine(_EngineBase):
                         lambda hh=h, ll=l: be.part_a(ll, hh, self.session.prefix_len),
                         flops=self._cost_part_a(s), tag="compute")
                 if not self.prefetch:
-                    self._submit_units(l, list(selected), trace, handles, clock)
+                    with obs.span("eng.submit", rid=rid):
+                        self._submit_units(l, list(selected), trace, handles,
+                                           clock)
                 yield from self._wait_keys(l, selected, handles, trace, "kv_io", clock)
-                k_sel, v_sel, valid = self._gather_chunks(l, selected, meta.chunk_tokens)
+                with obs.span("eng.gather", rid=rid):
+                    k_sel, v_sel, valid = self._gather_chunks(
+                        l, selected, meta.chunk_tokens)
                 if keep_suffix_kv:
                     kv_suffix[l] = (k_suf, v_suf)
                 h, mass = yield from self._part_b_ops(
@@ -891,10 +925,13 @@ class ContiguousKVEngine(_EngineBase):
                     ctx=self._chunk_ctx(l, h, q, k_suf, v_suf, k_sel, v_sel,
                                         valid, meta.chunk_tokens))
                 # attention-guided cache updates (Eq. 1/2)
-                if isinstance(self.cache, AttentionGuidedCache) and mass is not None:
-                    for i, u in enumerate(selected):
-                        self.cache.update_importance(self._key(l, u), float(mass[i]))
-                self._insert_cache(l, selected)
+                with obs.span("eng.cache", rid=rid):
+                    if (isinstance(self.cache, AttentionGuidedCache)
+                            and mass is not None):
+                        for i, u in enumerate(selected):
+                            self.cache.update_importance(self._key(l, u),
+                                                         float(mass[i]))
+                    self._insert_cache(l, selected)
 
         logits = yield ComputeOp(lambda hh=h: be.logits(hh),
                                  flops=2.0 * cfg.d_model * cfg.vocab_size, tag="compute")
@@ -1194,7 +1231,7 @@ class StateSpaceEngine:
     def plan(self, suffix_tokens, request_id: int = 0,
              arrival: float = 0.0, decode_tokens: int = 0) -> StepPlan:
         clock = RequestClock(arrival)
-        trace = ReprefillTrace(system=self.name)
+        trace = ReprefillTrace(system=self.name, request_id=request_id)
         gen = self._steps(np.asarray(suffix_tokens), request_id, clock, trace,
                           decode_tokens=decode_tokens)
         return StepPlan(request_id=request_id, gen=gen, clock=clock,

@@ -150,5 +150,7 @@ def decode_attention(
             jax.ShapeDtypeStruct((b, n_q, n_active), jnp.float32),
         ],
         interpret=interpret,
+        # the HLO instruction's name: a trace finds the kernel by it
+        name="decode_attention",
     )(page_table, lengths, q, k_tiles, v_tiles)
     return out, mass

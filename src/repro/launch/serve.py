@@ -68,6 +68,11 @@ one real backend per tenant model (``--fleet`` with real-mode
 ``--replicas``/``--disaggregate`` is rejected — per-model worker backends
 are not wired yet).
 
+``--trace-dir DIR`` (real mode) turns on the program's spans
+(:mod:`repro.obs`: ``ckv/sched.*``, ``ckv/eng.*``, ``ckv/store.read``,
+``ckv/step.*``) and writes a profiler trace of the request loop under DIR,
+host spans and device operations on one clock.
+
 ``--cache-tiers HBM:DRAM:SSD`` (unit capacities, contiguous_kv) upgrades the
 shared cache to the content-addressed three-tier
 :class:`repro.storage.tierstore.TieredPrefixStore`: host-DRAM victims demote
@@ -174,6 +179,19 @@ def use_compile_cache() -> None:
 
     root = pathlib.Path(__file__).resolve().parents[3]
     jax.config.update("jax_compilation_cache_dir", str(root / ".jax_cache"))
+
+
+def run_requests(sched: Scheduler, requests, args) -> list:
+    """Serve `requests`; with ``--trace-dir`` the program's spans are on
+    and a profiler trace of the request loop is written there."""
+    if not args.trace_dir:
+        return sched.run(requests)
+    from repro import obs
+
+    with obs.trace_to(args.trace_dir):
+        completed = sched.run(requests)
+    print(f"profiler trace of the request loop: {args.trace_dir}")
+    return completed
 
 
 def real_config(name: str, args):
@@ -292,7 +310,7 @@ def serve_real(args) -> RealRun:
                       swap_on_preempt=args.swap_on_preempt,
                       prefill_estimate=args.prefill_estimate,
                       topology=topology, replicas=replicas)
-    completed = sched.run(requests)
+    completed = run_requests(sched, requests, args)
 
     correct = 0
     for c in completed:
@@ -406,7 +424,7 @@ def _real_fleet_main(args):
                       preempt=args.preempt,
                       swap_on_preempt=args.swap_on_preempt,
                       prefill_estimate=args.prefill_estimate)
-    completed = sched.run(requests)
+    completed = run_requests(sched, requests, args)
     for c in completed:
         tr = c.trace
         dec = (f" tpot={tr.tpot*1e3:6.1f}ms ({tr.n_decoded} tok)"
@@ -581,6 +599,9 @@ def parse_args(argv=None) -> argparse.Namespace:
                         "the mesh GQA-style into (kv=K, rep=n/K), K=0 uses "
                         "one flat tensor axis")
     # real mode
+    p.add_argument("--trace-dir", default=None, metavar="DIR",
+                   help="real mode: enable the program's spans and write a "
+                        "profiler trace of the request loop under DIR")
     p.add_argument("--arch", default="qwen2.5-7b")
     p.add_argument("--dataset", default="rte")
     p.add_argument("--n-layers", type=int, default=8,
@@ -622,6 +643,8 @@ def parse_args(argv=None) -> argparse.Namespace:
         p.error("--tenants must be >= 1")
     if args.concurrency < 1:
         p.error("--concurrency must be >= 1")
+    if args.trace_dir and args.mode == "sim":
+        p.error("--trace-dir traces the real mode's request loop")
     return args
 
 

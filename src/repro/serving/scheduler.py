@@ -66,6 +66,7 @@ from typing import Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
+from repro import obs
 from repro.core import costmodel as CM
 from repro.core.cache import DEVICE, HOST
 from repro.core.stepplan import (
@@ -97,6 +98,10 @@ class Request:
 
 @dataclasses.dataclass
 class CompletedRequest:
+    """One served request.  `admitted` and `finish` are executor-clock
+    times; `start` is the run's start on that clock (0 in sim), and the
+    request fell due at ``start + request.arrival``."""
+
     request: Request
     trace: object  # ReprefillTrace
     result: object  # logits (real mode) / None (sim)
@@ -104,6 +109,12 @@ class CompletedRequest:
     finish: float
     preemptions: int = 0  # times this plan was preempted under SLO pressure
     swaps: int = 0  # swap-out/swap-in round trips of its resident units
+    start: float = 0.0
+
+    @property
+    def due(self) -> float:
+        """Executor-clock time the request arrived."""
+        return self.start + self.request.arrival
 
     @property
     def ttft(self) -> float:
@@ -112,16 +123,16 @@ class CompletedRequest:
         first-token time comes from the trace, not from `finish`.)"""
         if getattr(self.trace, "ttft", 0.0):
             return self.queue_delay + self.trace.ttft
-        return self.finish - self.request.arrival
+        return self.finish - self.due
 
     @property
     def e2e_latency(self) -> float:
         """Arrival to last emitted token (== ttft when decode_tokens=0)."""
-        return self.finish - self.request.arrival
+        return self.finish - self.due
 
     @property
     def queue_delay(self) -> float:
-        return self.admitted - self.request.arrival
+        return self.admitted - self.due
 
     @property
     def service_time(self) -> float:
@@ -191,7 +202,7 @@ class _Active:
     __slots__ = ("request", "plan", "op", "resume", "admitted",
                  "preempt_count", "swap_count", "swapped_bytes", "ttft_seen",
                  "batch_stamp", "held_op", "handed_off", "worker_backend",
-                 "replica")
+                 "replica", "stall")
 
     def __init__(self, request: Request, plan: StepPlan, admitted: float):
         self.request = request
@@ -208,6 +219,10 @@ class _Active:
         self.handed_off = False  # prefill->decode handoff already emitted
         self.worker_backend = None  # real decode worker backend after handoff
         self.replica = 0  # owning replica index under a ReplicaSet
+        # real driver: [start, end] of the pending I/O wait the plan is
+        # stalled on (executor clock; end stamped at the transfer's
+        # completion), None while it is not stalled
+        self.stall = None
 
 
 # ---------------------------------------------------------------------------
@@ -300,6 +315,7 @@ class Scheduler:
         self.handoff_recomputes = 0  # handoffs the planner turned into
         self.handoff_bytes_avoided = 0  # ... decode-worker recomputes
         self._rr_decode = 0  # real mode: round-robin decode-worker pick
+        self._run_start = 0.0  # real mode: the run's start, executor clock
 
     def run(self, requests: Sequence[Request]) -> List[CompletedRequest]:
         requests = list(requests)
@@ -808,7 +824,8 @@ class Scheduler:
         done.append(CompletedRequest(a.request, a.plan.trace, value,
                                      a.admitted, self.ex.now(),
                                      preemptions=a.preempt_count,
-                                     swaps=a.swap_count))
+                                     swaps=a.swap_count,
+                                     start=self._run_start))
 
     def _start_real(self, req: Request, active, done):
         """Build one plan and admit it into the wall-clock driver."""
@@ -992,8 +1009,11 @@ class Scheduler:
         flops = sum(a.op.flops for a in members)
         weight = max(a.op.weight_bytes for a in members)
         hbm = weight + sum(a.op.hbm_bytes - a.op.weight_bytes for a in members)
-        outs = ex.compute(lambda: be.decode_step_batch(ctxs), flops=flops,
-                          hbm_bytes=hbm, tag=f"decode[x{len(members)}]")
+        outs = self._charged(
+            members, be,
+            lambda: ex.compute(lambda: be.decode_step_batch(ctxs),
+                               flops=flops, hbm_bytes=hbm,
+                               tag=f"decode[x{len(members)}]"))
         stamp = len(self.real_batch_log)
         for a in members:
             a.batch_stamp = stamp
@@ -1059,9 +1079,11 @@ class Scheduler:
         flops = sum(a.op.flops for a in members)
         weight = max(a.op.weight_bytes for a in members)
         hbm = weight + sum(a.op.hbm_bytes - a.op.weight_bytes for a in members)
-        outs = ex.compute(lambda: be.part_b_batch(ctxs), flops=flops,
-                          hbm_bytes=hbm,
-                          tag=f"prefill_chunk[x{len(members)}]")
+        outs = self._charged(
+            members, be,
+            lambda: ex.compute(lambda: be.part_b_batch(ctxs), flops=flops,
+                               hbm_bytes=hbm,
+                               tag=f"prefill_chunk[x{len(members)}]"))
         stamp = len(self.real_batch_log)
         for a in members:
             a.batch_stamp = stamp
@@ -1079,86 +1101,157 @@ class Scheduler:
                 active.remove(a)
                 self._finish_real(a, done, stop.value)
 
+    def _charged(self, members: List[_Active], be, run):
+        """`run()` one compute of `members`' ops on backend `be`, and share
+        the blocking device-to-host reads and upload bytes the backend made
+        in it evenly among the members' traces."""
+        syncs = getattr(be, "d2h_syncs", None)
+        if syncs is None:  # a backend without counters (sim, stubs)
+            return run()
+        up = be.h2d_bytes
+        out = run()
+        n = len(members)
+        for a in members:
+            a.plan.trace.d2h_syncs += (be.d2h_syncs - syncs) / n
+            a.plan.trace.h2d_bytes += (be.h2d_bytes - up) / n
+        return out
+
+    def _op_backend(self, a: _Active):
+        """The backend a plan's pending ComputeOp runs on: a decode step
+        follows its ctx (restamped at a disaggregated handoff), everything
+        else runs on the plan's engine."""
+        be = getattr(a.op.batch_ctx, "backend", None)
+        if be is None:
+            be = getattr(self.engines[a.request.tenant], "backend", None)
+        return be
+
+    def _stall_begins(self, a: _Active, fut):
+        """The first poll found `a`'s wait pending: start its stall now and
+        stamp its end, on the executor clock, when the transfer completes
+        (on the I/O thread, or here if it completed in between)."""
+        stall = [self.ex.now(), None]
+
+        def stamp(_):
+            stall[1] = self.ex.now()
+
+        a.stall = stall
+        fut.add_done_callback(stamp)
+
+    def _stall_ends(self, a: _Active):
+        """`a`'s pending wait is done: add its stall to the trace if the
+        plan has not yet produced its first token."""
+        start, end = a.stall
+        a.stall = None
+        trace = a.plan.trace
+        if not trace.ttft:
+            # a completion whose callback has not run yet ends now
+            trace.io_stall_s += (end if end is not None
+                                 else self.ex.now()) - start
+
     def _run_real(self, requests: List[Request]) -> List[CompletedRequest]:
+        """The wall-clock driver.  Spans (:mod:`repro.obs`): ``sched.run``
+        around the whole loop (the driver's own per-pass work is its self
+        time), ``sched.admit`` per admission, ``sched.decode_batch`` /
+        ``sched.chunk_batch`` per batched pass, ``sched.io_wait`` while
+        every plan waits on I/O, ``sched.idle`` while nothing is admitted
+        and the next arrival is in the future; none per op or per pass."""
         ex = self.ex
         pending = sorted(requests, key=lambda r: (r.arrival, r.request_id))
         active: List[_Active] = []
         preempted: List[_Active] = []
         done: List[CompletedRequest] = []
-        t0 = ex.now()
-        while pending or active or preempted:
-            self._resume_real(preempted, active)
-            # arrival-aware admission: only requests whose offset has passed
-            # on the wall clock may enter — the open-loop trace shape (and
-            # therefore what each iteration can batch) matches the sim driver
-            while pending and len(active) < self.max_concurrency:
-                arrived = [r for r in pending
-                           if r.arrival <= ex.now() - t0]
-                if not arrived:
-                    break
+        t0 = self._run_start = ex.now()
+        with obs.span("sched.run"):
+            while pending or active or preempted:
+                self._real_pass(pending, active, preempted, done, t0)
+        done.sort(key=lambda c: c.request.request_id)
+        return done
+
+    def _real_pass(self, pending, active, preempted, done, t0: float):
+        """One iteration of the wall-clock driver."""
+        ex = self.ex
+        self._resume_real(preempted, active)
+        # arrival-aware admission: only requests whose offset has passed
+        # on the wall clock may enter — the open-loop trace shape (and
+        # therefore what each iteration can batch) matches the sim driver
+        while pending and len(active) < self.max_concurrency:
+            arrived = [r for r in pending
+                       if r.arrival <= ex.now() - t0]
+            if not arrived:
+                break
+            with obs.span("sched.admit"):
                 req = self.policy.select(arrived, self.engines)
                 pending.remove(req)
                 self._start_real(req, active, done)
-            self._preempt_real(pending, active, preempted, t0, done)
-            progressed = False
-            # iteration-level batching: coalesce runnable decode steps into
-            # one kernel pass; prefill/IO ops keep the cooperative
-            # round-robin below.  Candidates left out of this iteration's
-            # batch (backend mismatch, token budget) stay runnable and are
-            # skipped this pass so no plan advances twice per iteration.
-            members = self._real_decode_batch(active)
-            skip = set()
-            if members is not None:
+        self._preempt_real(pending, active, preempted, t0, done)
+        progressed = False
+        # iteration-level batching: coalesce runnable decode steps into
+        # one kernel pass; prefill/IO ops keep the cooperative
+        # round-robin below.  Candidates left out of this iteration's
+        # batch (backend mismatch, token budget) stay runnable and are
+        # skipped this pass so no plan advances twice per iteration.
+        members = self._real_decode_batch(active)
+        skip = set()
+        if members is not None:
+            with obs.span("sched.decode_batch"):
                 self._step_real_batch(members, active, done)
-                progressed = True
-                skip = {id(a) for a in active
-                        if isinstance(a.op, ComputeOp)
-                        and a.op.phase == "decode"
-                        and a.op.batch_ctx is not None}
-            # same-layer prefill chunk coalescing (disjoint from the decode
-            # batch: different phase, so no plan can be in both)
-            chunk_members = self._real_chunk_batch(active)
-            if chunk_members is not None:
+            progressed = True
+            skip = {id(a) for a in active
+                    if isinstance(a.op, ComputeOp)
+                    and a.op.phase == "decode"
+                    and a.op.batch_ctx is not None}
+        # same-layer prefill chunk coalescing (disjoint from the decode
+        # batch: different phase, so no plan can be in both)
+        chunk_members = self._real_chunk_batch(active)
+        if chunk_members is not None:
+            with obs.span("sched.chunk_batch"):
                 self._step_real_chunk_batch(chunk_members, active, done)
-                progressed = True
-                skip |= {id(a) for a in active
-                         if isinstance(a.op, ComputeOp)
-                         and a.op.phase == "prefill"
-                         and isinstance(a.op.batch_ctx, PrefillChunkCtx)}
-            for a in list(active):
-                if id(a) in skip:
-                    continue
-                op = a.op
-                if isinstance(op, WaitOp):
-                    f = op.handle.future
-                    if f is not None and not f.done():
-                        continue  # not ready: let another plan use the window
-                    send = resolve_handle(op.handle)
-                else:
-                    send = ex.compute(op.fn, flops=op.flops,
-                                      hbm_bytes=op.hbm_bytes, tag=op.tag)
-                a.plan.clock.t = ex.now()
-                progressed = True
-                try:
-                    a.op = a.plan.gen.send(send)
-                    self._observe_ttft(a)
-                    self._maybe_handoff_real(a)
-                except StopIteration as stop:
-                    active.remove(a)
-                    self._finish_real(a, done, stop.value)
-            if not progressed and active:
-                # every plan is blocked on a pending future: sleep on the I/O
-                futs = [a.op.handle.future for a in active
-                        if isinstance(a.op, WaitOp) and a.op.handle.future is not None]
+            progressed = True
+            skip |= {id(a) for a in active
+                     if isinstance(a.op, ComputeOp)
+                     and a.op.phase == "prefill"
+                     and isinstance(a.op.batch_ctx, PrefillChunkCtx)}
+        for a in list(active):
+            if id(a) in skip:
+                continue
+            op = a.op
+            if isinstance(op, WaitOp):
+                f = op.handle.future
+                if f is not None and not f.done():
+                    if a.stall is None:
+                        self._stall_begins(a, f)
+                    continue  # not ready: let another plan use the window
+                if a.stall is not None:
+                    self._stall_ends(a)
+                send = resolve_handle(op.handle)
+            else:
+                send = self._charged(
+                    [a], self._op_backend(a),
+                    lambda: ex.compute(op.fn, flops=op.flops,
+                                       hbm_bytes=op.hbm_bytes, tag=op.tag))
+            a.plan.clock.t = ex.now()
+            progressed = True
+            try:
+                a.op = a.plan.gen.send(send)
+                self._observe_ttft(a)
+                self._maybe_handoff_real(a)
+            except StopIteration as stop:
+                active.remove(a)
+                self._finish_real(a, done, stop.value)
+        if not progressed and active:
+            # every plan is blocked on a pending future: sleep on the I/O
+            futs = [a.op.handle.future for a in active
+                    if isinstance(a.op, WaitOp)
+                    and a.op.handle.future is not None]
+            with obs.span("sched.io_wait"):
                 futures_wait(futs, return_when=FIRST_COMPLETED)
-            elif not progressed and pending:
-                # idle system, all remaining traffic is in the future: sleep
-                # through the gap to the next arrival instead of spinning
-                gap = min(r.arrival for r in pending) - (ex.now() - t0)
-                if gap > 0:
+        elif not progressed and pending:
+            # idle system, all remaining traffic is in the future: sleep
+            # through the gap to the next arrival instead of spinning
+            gap = min(r.arrival for r in pending) - (ex.now() - t0)
+            if gap > 0:
+                with obs.span("sched.idle"):
                     time.sleep(gap)
-        done.sort(key=lambda c: c.request.request_id)
-        return done
 
 
 # ---------------------------------------------------------------------------
@@ -1173,7 +1266,7 @@ def summarize(completed: Sequence[CompletedRequest]) -> Dict[str, float]:
     if not completed:
         return {"n": 0}
     ttfts = np.array([c.ttft for c in completed])
-    arrivals = np.array([c.request.arrival for c in completed])
+    arrivals = np.array([c.due for c in completed])
     finishes = np.array([c.finish for c in completed])
     makespan = float(finishes.max() - arrivals.min())
     out = {

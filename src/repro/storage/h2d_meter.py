@@ -15,7 +15,10 @@ and the benchmark's host-pool H2D row) exist to catch the meter going
 blind on the path that matters.  Both the no-reupload test and
 ``benchmarks/bench_throughput.py``'s pool-residency gate count through
 this one class — if backends ever grows a third transfer door, this is
-the single place to teach it.
+the single place to teach it.  ``RealCompute.h2d_bytes`` counts the
+backend's own uploads in production (its one door, ``_to_device``, goes
+through ``jnp.asarray``); this meter is the test oracle that the count is
+complete.
 """
 from __future__ import annotations
 

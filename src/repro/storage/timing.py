@@ -117,8 +117,6 @@ class RealExecutor(BaseExecutor):
     def __init__(self, n_io_threads: int = 4):
         self.pool = ThreadPoolExecutor(max_workers=n_io_threads)
         self._t0 = time.perf_counter()
-        self.compute_busy = 0.0
-        self.stage_times: Dict[str, float] = {}
 
     def now(self) -> float:
         return time.perf_counter() - self._t0
@@ -130,12 +128,10 @@ class RealExecutor(BaseExecutor):
         handle.done_result()
 
     def compute(self, fn, *, flops=0.0, hbm_bytes=0.0, tag=""):
-        t0 = time.perf_counter()
-        out = fn() if fn is not None else None
-        dt = time.perf_counter() - t0
-        self.compute_busy += dt
-        self.stage_times[tag] = self.stage_times.get(tag, 0.0) + dt
-        return out
+        """Run `fn` on this thread (its device work is dispatched, not
+        awaited: where the time goes is read from ``step.*`` spans on the
+        profiler's clock, :mod:`repro.obs`)."""
+        return fn() if fn is not None else None
 
     def shutdown(self):
         self.pool.shutdown(wait=True)

@@ -7,6 +7,8 @@ topology is described inside a fixture, never at import, so every test
 worker collects the same tests and only the one running this file loads the
 TPU library.  All cases live in this one file for the same reason.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -65,3 +67,29 @@ def test_selective_scan_compiles(one_chip, s, block_s, block_d):
         one_chip, ((b, s, d_in), bf16), ((b, s), f32), ((d_in, n), f32),
         ((b, s, n), bf16), ((b, s, n), bf16), ((b, d_in, n), f32))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decode_attention_names_its_kernel(one_chip, monkeypatch):
+    """On the serving path (``decode_attention_pools``) the paged
+    decode-attention kernel is the HLO instruction ``decode_attention``:
+    the name the chip benchmark's trace reduction finds it by."""
+    from repro.kernels.decode_attention import ops
+
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    n_q, n_kv, d, page, n_pages = 28, 4, 128, 16, 80
+    bf16 = jnp.bfloat16
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = (spec((n_pages, page, n_kv, d), bf16),)
+    jax.clear_caches()  # trace afresh with the kernel compiled, not interpreted
+    try:
+        hlo = ops.decode_attention_pools.lower(
+            spec((1, n_q, d), bf16), pool, pool,
+            spec((1, n_pages), jnp.int32), spec((1,), jnp.int32),
+        ).compile().as_text()
+    finally:
+        jax.clear_caches()
+    assert re.search(r"^\s*%decode_attention(\.\d+)? = .* custom-call\(",
+                     hlo, re.M)
