@@ -231,13 +231,12 @@ def _pool_write_device(k, v, k_tok, v_tok, p, s):
             jax.lax.dynamic_update_slice(v, v_tok, idx))
 
 
-@partial(jax.jit, donate_argnums=(0, 1))
-def _pool_write_batch_device(ks, vs, k_cur, v_cur, slots):
-    """Append request i's `k_cur[i]`/`v_cur[i]` into donated pool buffer i
-    at page `slots[i, 0]`, offset `slots[i, 1]`, and return the updated
-    buffers together with their ragged zero-padded stack — the whole
-    batch's pool maintenance *and* batch assembly in one dispatch, reading
-    and writing device memory only."""
+def _pool_write_batch(ks, vs, k_cur, v_cur, slots):
+    """Append request i's `k_cur[i]`/`v_cur[i]` into pool buffer i at page
+    `slots[i, 0]`, offset `slots[i, 1]`, and return the updated buffers
+    together with their ragged zero-padded stack — the whole batch's pool
+    maintenance *and* batch assembly, reading and writing device memory
+    only."""
     new_ks, new_vs = [], []
     for i, (k, v) in enumerate(zip(ks, vs)):
         kt = k_cur[i].reshape(1, 1, *k.shape[2:]).astype(k.dtype)
@@ -249,6 +248,53 @@ def _pool_write_batch_device(ks, vs, k_cur, v_cur, slots):
     return tuple(new_ks), tuple(new_vs), k_pool, v_pool
 
 
+# donated: the writes land in place, in one dispatch per layer
+_pool_write_batch_device = jax.jit(_pool_write_batch, donate_argnums=(0, 1))
+
+
+@partial(jax.jit, static_argnames=("cfg",), donate_argnames=("ks", "vs"))
+def _decode_token(params, ctrl, ks, vs, cfg: ModelConfig):
+    """One decode position of b requests through every layer, as one program.
+
+    ``ks``/``vs`` hold, per layer, the b requests' :class:`DeviceTailPool`
+    buffers; they are donated, so each layer's K/V write lands in place.
+    ``ctrl`` packs the int32 control operands, one row per request: the
+    token, its position, then per layer the write slot (page, offset), the
+    valid length including this token and the page table padded with -1 to
+    the layer's largest pool.  Each layer's weights are a static slice of
+    the stacked ``params["layers"]``, read in place by the ops that use
+    them.  Returns the float32 logits (b, 1, vocab), the updated buffers
+    and the head-averaged per-page masses (L, b, width), zero past each
+    layer's own table width.
+    """
+    b = ctrl.shape[0]
+    h = params["embed"][ctrl[:, :1]]
+    positions = ctrl[:, 1:2]
+    widths = [max(k.shape[0] for k in ks_l) for ks_l in ks]
+    col = 2
+    new_ks, new_vs, masses = [], [], []
+    for l, width in enumerate(widths):
+        lp = _slice_layer(params, l)
+        slots = ctrl[:, col: col + 2]
+        lengths = ctrl[:, col + 2]
+        table = ctrl[:, col + 3: col + 3 + width]
+        col += 3 + width
+        _, q, k_cur, v_cur = _part_a_at(lp, h, cfg, positions)
+        ks_l, vs_l, k_pool, v_pool = _pool_write_batch(ks[l], vs[l], k_cur,
+                                                       v_cur, slots)
+        new_ks.append(ks_l)
+        new_vs.append(vs_l)
+        out, page_mass = decode_attention(q[:, 0], k_pool, v_pool, table,
+                                          lengths)
+        attn = out.reshape(b, 1, cfg.n_heads, cfg.d_head)
+        h = h + jnp.einsum("bshk,hkd->bsd", attn, lp["wo"])
+        h = _ffn(h, lp, cfg, dropless=True)
+        mass = page_mass.mean(axis=1)  # (b, width)
+        masses.append(jnp.pad(mass, ((0, 0), (0, max(widths) - width))))
+    logits = _final_logits_kernel(params, h, cfg.norm_eps)
+    return logits, tuple(new_ks), tuple(new_vs), jnp.stack(masses)
+
+
 class DeviceTailPool(TailPool):
     """Device-resident TailPool: one H2D upload at decode start, zero after.
 
@@ -258,12 +304,14 @@ class DeviceTailPool(TailPool):
     at construction; each decode step's token KV — already on device as a
     slice of part-A's output — lands via a donated
     ``lax.dynamic_update_slice`` jit, so XLA aliases the buffer and no pool
-    bytes ever cross PCIe again.  Control-plane operands stay tiny: the b=1
-    attend path uploads the page table only when ``n_active`` changes (a
-    page boundary crossing, via the ``device_table`` cache) plus a 4-byte
-    ``lengths`` scalar per attend, while the batched driver re-sends its
-    ``(b, width)`` int32 table each step (int32s, not pool bytes — the
-    benchmark's H2D meter counts them).  ``swap_out``/``swap_in``
+    bytes ever cross PCIe again.  Control-plane operands stay tiny: the
+    serving step (``RealCompute.decode_step_batch``) uploads one packed
+    int32 array a token — slots, lengths and ``(b, width)`` tables of every
+    layer (int32s, not pool bytes — the benchmark's H2D meter counts them);
+    the per-layer ``decode_attend`` uploads the page table only when
+    ``n_active`` changes (a page boundary crossing, via the
+    ``device_table`` cache) plus a 4-byte ``lengths`` scalar per attend.
+    ``swap_out``/``swap_in``
     round-trip the buffers to
     host numpy bit-identically — the real scheduler uses them to free a
     preempted request's device state and restore it on resume.
@@ -453,11 +501,13 @@ class RealCompute:
     contract) validated bit-close to the single-device kernel.
 
     Counters: ``d2h_syncs`` counts the blocking device-to-host reads of
-    device results (every one goes through :meth:`_to_host`) and
+    device results (every one goes through :meth:`_to_host`),
     ``h2d_bytes`` the bytes of host arrays uploaded by these methods (every
-    one goes through :meth:`_to_device`).  Each method runs inside a
-    ``step.<method>`` span, each read inside ``step.d2h`` and each upload
-    inside ``step.h2d`` (:mod:`repro.obs`)."""
+    one goes through :meth:`_to_device`) and ``fused_decode_tokens`` the
+    decode tokens served by the one-program step (:func:`_decode_token`).
+    Each method runs inside a ``step.<method>`` span, each read inside
+    ``step.d2h``, each upload inside ``step.h2d`` and each one-program
+    decode step inside ``step.decode_token`` (:mod:`repro.obs`)."""
 
     def __init__(self, cfg: ModelConfig, params, *, tp_mesh=None):
         assert cfg.has_attention, "Re-Prefill engine needs attention KV"
@@ -466,6 +516,7 @@ class RealCompute:
         self.tp_mesh = tp_mesh
         self.d2h_syncs = 0
         self.h2d_bytes = 0
+        self.fused_decode_tokens = 0
         if tp_mesh is not None:
             # lazy import: core must stay importable without launch/
             from repro.launch.sharded_sparse import make_sharded_paged_decode
@@ -474,10 +525,13 @@ class RealCompute:
         else:
             self._tp_attend = None
 
-    def _to_host(self, x, dtype=None) -> np.ndarray:
-        """Blocking read of a device result into host memory (counted)."""
+    def _to_host(self, x, dtype=None):
+        """Blocking read of a device result, or of a tuple of them in one
+        transfer, into host memory (counted once)."""
         self.d2h_syncs += 1
         with obs.span("step.d2h"):
+            if isinstance(x, tuple):
+                return tuple(np.asarray(a) for a in jax.device_get(x))
             return np.asarray(x, dtype)
 
     def _to_device(self, x):
@@ -632,14 +686,20 @@ class RealCompute:
 
         `ctxs` are :class:`repro.core.stepplan.DecodeBatchCtx` handles the
         engines stamped on their decode ComputeOps: input token, absolute
-        position, and the per-layer TailPools.  One embed / part-A / paged
-        decode-attention / FFN pass runs per layer for the whole ragged batch
-        (per-request page tables padded to a common width, `lengths` masking
-        the pads), amortizing the weight stream the way the sim scheduler's
+        position, and the per-layer TailPools.  Device pools on one device
+        run every layer as one program (:func:`_decode_token`): one packed
+        upload of the control operands and one read of logits and masses
+        per token.  Host pools and the tensor-parallel attend run a
+        per-layer loop: one embed / part-A / paged decode-attention / FFN
+        pass per layer for the whole ragged batch (per-request page tables
+        padded to a common width, `lengths` masking the pads).  Either way
+        the weights stream once for the batch, the way the sim scheduler's
         `compute_batch_at` prices it.  Returns one (logits, masses) pair per
         request, in `ctxs` order — exactly what the per-request generators
         expect from their single-request `fn`.
         """
+        if ctxs[0].pools[0].is_device and self._tp_attend is None:
+            return self._decode_fused(ctxs)
         cfg = self.cfg
         put = self._to_device
         b = len(ctxs)
@@ -691,6 +751,34 @@ class RealCompute:
         logits = self._to_host(
             _final_logits_kernel(self.params, h, cfg.norm_eps))
         return [(logits[i: i + 1], masses[i]) for i in range(b)]
+
+    def _decode_fused(self, ctxs):
+        """:meth:`decode_step_batch` over device pools, as one program."""
+        layers = [[c.pools[l] for c in ctxs] for l in range(self.cfg.n_layers)]
+        ctrl = [[c.token, c.pos] for c in ctxs]
+        for pools in layers:
+            width = max(p.k.shape[0] for p in pools)
+            for row, p in zip(ctrl, pools):
+                p._check_capacity(1)
+                row.extend(p.slot())
+                p.t += 1
+                row.append(p.valid_tokens)
+                row.extend(p.table(width))
+        with obs.span("step.decode_token"):
+            logits, ks, vs, mass = _decode_token(
+                self.params, self._to_device(np.array(ctrl, np.int32)),
+                tuple(tuple(p.k for p in pools) for pools in layers),
+                tuple(tuple(p.v for p in pools) for pools in layers),
+                self.cfg)
+            self.fused_decode_tokens += len(ctxs)
+            for pools, ks_l, vs_l in zip(layers, ks, vs):
+                for p, k, v in zip(pools, ks_l, vs_l):
+                    p.k, p.v = k, v
+            logits, mass = self._to_host((logits, mass))
+        return [(logits[i: i + 1],
+                 {l: mass[l, i, : pools[i].n_res]
+                  for l, pools in enumerate(layers)})
+                for i in range(len(ctxs))]
 
 
 class SimCompute:

@@ -757,21 +757,13 @@ class _EngineBase:
                 ctx = DecodeBatchCtx(backend=be, token=tok, pos=pos,
                                      pools=pools)
 
-                def fn(tok_now=tok, pos=pos, pools=pools, ctx=ctx):
+                def fn(ctx=ctx):
                     # the backend comes off the ctx, not the closure: a
                     # disaggregated scheduler reassigns ctx.backend at the
                     # KV handoff, and the standalone path must follow the
                     # plan onto the decode worker's engine just like the
                     # batched path does
-                    bk = ctx.backend
-                    h = bk.embed(np.array([tok_now]))
-                    masses = {}
-                    for l in range(cfg.n_layers):
-                        # traced positions: one jit entry for every step
-                        _, q, k_cur, v_cur = bk.part_a_at(l, h, [[pos]])
-                        pools[l].append(k_cur, v_cur)
-                        h, masses[l] = bk.decode_attend(l, h, q, pools[l])
-                    return bk.logits(h), masses
+                    return ctx.backend.decode_step_batch([ctx])[0]
 
             out = yield ComputeOp(self._bound(request_id, fn) if fn else None,
                                   flops=cost.flops, hbm_bytes=cost.hbm_bytes,

@@ -25,8 +25,9 @@ Span names, by layer:
   engine      eng.select, eng.submit, eng.gather, eng.cache (arg rid)
   storage     store.read, on the I/O thread (args rid, layer, bytes)
   model step  step.<RealCompute method>, and inside them step.d2h around
-              each blocking device-to-host read and step.h2d around each
-              upload
+              each blocking device-to-host read, step.h2d around each
+              upload and step.decode_token around each one-program decode
+              step
 
 A span never stays open across a ``yield`` of a step-plan generator (other
 plans run in between), and none is opened per op, per wait or per driver

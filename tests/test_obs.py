@@ -145,8 +145,8 @@ def test_spans_appear_with_their_names_and_arguments(traced_run):
     want = {"sched.run", "sched.admit", "sched.idle", "sched.io_wait",
             "eng.select", "eng.submit", "eng.gather", "eng.cache",
             "store.read", "step.embed", "step.part_a", "step.token_scores",
-            "step.part_b", "step.logits", "step.part_a_at",
-            "step.decode_attend", "step.d2h", "step.h2d"}
+            "step.part_b", "step.logits", "step.decode_step_batch",
+            "step.decode_token", "step.d2h", "step.h2d"}
     assert {obs.PREFIX + n for n in want} <= names
     rids = {c.request.request_id for c in done}
     for name, _, _, stats in events:
@@ -198,7 +198,7 @@ def test_host_syncs_per_b1_decode_token(model):
                 steps.append(be.d2h_syncs - before)
         else:
             send = resolve_handle(op.handle)
-    assert steps == [cfg.n_layers + 1] * 3
+    assert steps == [1] * 3
 
 
 def test_h2d_bytes_match_the_meter_over_a_reprefill(model):

@@ -93,3 +93,73 @@ def test_decode_attention_names_its_kernel(one_chip, monkeypatch):
         jax.clear_caches()
     assert re.search(r"^\s*%decode_attention(\.\d+)? = .* custom-call\(",
                      hlo, re.M)
+
+
+# one layer's weight matrices at qwen2.5-7b widths: w_gate/w_up, w_down,
+# wq, wo
+WEIGHTS = ("3584,18944", "18944,3584", "3584,28,128", "28,128,3584")
+
+
+def _weight_copies(hlo: str):
+    """Instructions of the optimised HLO's entry computation that copy or
+    dynamic-slice one layer's weight matrix (leading layer axis of 1 or
+    none) into a buffer of its own."""
+    entry = hlo[hlo.index("\nENTRY "):]
+    entry = entry[: entry.index("\n}")]
+    pat = re.compile(
+        r"^\s*(?:ROOT )?%(\S+) = \(?bf16\[(?:1,)?(?:" + "|".join(WEIGHTS)
+        + r")\]\S* (?:copy|copy-start|copy-done|dynamic-slice|fusion)\(",
+        re.M)
+    return [m.group(1) for m in pat.finditer(entry)]
+
+
+def test_weight_copy_check_finds_an_eager_layer_slice(one_chip):
+    """The check below, on what slicing a layer out of the stack as its own
+    program (the per-layer decode path's ``_slice_layer``) compiles to."""
+    stack = ((2, 3584, 18944), jnp.bfloat16)
+    hlo = _compile(lambda w, l: w[l], one_chip, stack,
+                   ((), jnp.int32)).as_text()
+    assert _weight_copies(hlo)
+
+
+@pytest.mark.parametrize("b", [1, 8])
+def test_fused_decode_step_reads_weights_in_place(one_chip, monkeypatch, b):
+    """The one-program decode step (``backends._decode_token``) at
+    qwen2.5-7b published widths, 2 layers, over the decode cell's pools
+    (128 resident pages and 12 tail pages of 16 tokens): it compiles for the
+    chip, runs the ``decode_attention`` kernel once per layer under its own
+    name, and indexes the stacked layer weights without copying a layer's
+    matrix out."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.core import backends
+    from repro.kernels.decode_attention import ops
+    from repro.models import transformer as T
+
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config("qwen2.5-7b"), n_layers=2)
+    assert (cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.n_kv_heads,
+            cfg.d_head) == (3584, 18944, 28, 4, 128)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda k: T.init_params(k, cfg),
+                       jax.random.PRNGKey(0)))
+    n_pages = 140
+    pool = spec((n_pages, 16, cfg.n_kv_heads, cfg.d_head), jnp.bfloat16)
+    pools = tuple((pool,) * b for _ in range(cfg.n_layers))
+    ctrl = spec((b, 2 + cfg.n_layers * (3 + n_pages)), jnp.int32)
+    jax.clear_caches()  # trace afresh with the kernel compiled, not interpreted
+    try:
+        hlo = backends._decode_token.lower(
+            params, ctrl, pools, pools, cfg).compile().as_text()
+    finally:
+        jax.clear_caches()
+    kernels = re.findall(r"^\s*%decode_attention(?:\.\d+)? = .* custom-call\(",
+                         hlo, re.M)
+    assert len(kernels) == cfg.n_layers
+    assert _weight_copies(hlo) == []
