@@ -72,12 +72,13 @@ def knee(lines) -> float:
 def sweep(workload: str, seeds, rates, seconds: float):
     from chipbench import cell as C
 
-    cell_entry, conf, mix, _ = RUN.resolve(RUN.load_bench(), workload, False)
+    cell_entry, conf, mix, _, arch = RUN.resolve(RUN.load_bench(), workload,
+                                                 False)
     RUN.device_info(cell_entry["chips"])
     RUN.use_compile_cache(RUN.import_system())
     lines = []
     for seed in seeds:
-        cell = C.Cell(conf, mix, seed)
+        cell = C.Cell(conf, mix, seed, arch)
         t = time.perf_counter()
         cell.build()
         cell.warm_up()

@@ -25,7 +25,6 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from chipbench import reference as R
 from chipbench import trace_reduce as TR
 from chipbench import traffic as T
 
@@ -61,20 +60,6 @@ class CompileCounter:
         elif event == self.COMPILE:
             self.compiled += 1
             self.compile_s += duration
-
-
-def program_config(conf: dict):
-    """The serving code's ModelConfig, with every size from the file."""
-    from repro.configs import get_config
-
-    d = R.dims_of(conf)
-    return dataclasses.replace(
-        get_config(conf["arch"]), n_layers=d.layers, d_model=d.d,
-        n_heads=d.nq, n_kv_heads=d.nkv, d_head=d.dh, d_ff=d.ff,
-        vocab_size=d.vocab, qk_norm=d.qk_norm, qkv_bias=d.qkv_bias,
-        rope_theta=d.theta, norm_eps=d.eps,
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        dtype=conf["torch_dtype"])
 
 
 @dataclasses.dataclass
@@ -194,11 +179,15 @@ def _executor_class():
 
 
 class Cell:
-    """One configuration under one traffic mix, built from one seed."""
+    """One configuration under one traffic mix, built from one seed.
 
-    def __init__(self, conf: dict, mix: dict, seed: int):
-        self.conf, self.mix, self.seed = conf, mix, seed
-        self.dims = R.dims_of(conf)
+    `arch` is the configuration's architecture module (``run.architecture``):
+    the cell takes its sizes, the program's config, the weights and the
+    reference from it."""
+
+    def __init__(self, conf: dict, mix: dict, seed: int, arch):
+        self.conf, self.mix, self.seed, self.arch = conf, mix, seed, arch
+        self.dims = arch.dims_of(conf)
         self.sysconf = conf["system"]
         self.records: Dict[int, Record] = {}
         self.kernel_calls: List[tuple] = []
@@ -216,8 +205,8 @@ class Cell:
 
         steps = {}
         t = time.perf_counter()
-        self.cfg = program_config(self.conf)
-        self.params = R.make_params(self.conf, self.seed)
+        self.cfg = self.arch.program_config(self.conf)
+        self.params = self.arch.make_params(self.conf, self.seed)
         jax.block_until_ready(self.params)
         steps["init"] = time.perf_counter() - t
 
@@ -436,6 +425,22 @@ def logit_error(prog: np.ndarray, ref: np.ndarray) -> np.ndarray:
             / np.linalg.norm(dev, axis=1))
 
 
+def token_gaps(ref_logits: np.ndarray, tokens: np.ndarray) -> np.ndarray:
+    """How far each token's reference logit lies below the reference's best."""
+    rows = np.arange(len(tokens))
+    return ref_logits.max(axis=1) - ref_logits[rows, tokens]
+
+
+def selection_gap(ref_scores: np.ndarray, selected: np.ndarray,
+                  budget: float) -> float:
+    """How far the weakest selected chunk's reference score lies below the
+    k-th best reference score, in units of the mean chunk score."""
+    k = min(len(ref_scores), max(1, math.ceil(budget * len(ref_scores))))
+    kth = np.sort(ref_scores)[::-1][k - 1]
+    weakest = ref_scores[np.asarray(selected, int)].min()
+    return float(max(0.0, kth - weakest) / ref_scores.mean())
+
+
 def check(cell: Cell, run: dict, *, control: bool = False) -> dict:
     """Compare what the window served with the reference, over every request
     that served a token: the widest relative error of the logits behind a
@@ -448,8 +453,9 @@ def check(cell: Cell, run: dict, *, control: bool = False) -> dict:
     own logits and, as the token served at each position, the one it puts
     first; the reference and the comparison are the same."""
     reqs = [r for r in run["requests"] if r.served() is not None]
-    ref = R.Reference(cell.params, cell.conf, cell.prefix)
-    ctl = (R.Reference(cell.params, cell.conf, cell.prefix, quant=True)
+    arch = cell.arch
+    ref = arch.Reference(cell.params, cell.conf, cell.prefix)
+    ctl = (arch.Reference(cell.params, cell.conf, cell.prefix, quant=True)
            if control else None)
     budget = cell.sysconf["budget"]
     out = {"logit_err": 0.0, "selection_gap": 0.0, "not_greedy": 0,
@@ -467,10 +473,10 @@ def check(cell: Cell, run: dict, *, control: bool = False) -> dict:
         out["logit_err"] = max(out["logit_err"],
                                float(logit_error(prog, logits).max()))
         out["token_gap"] = max(out["token_gap"],
-                               float(R.token_gaps(logits, tokens).max()))
+                               float(token_gaps(logits, tokens).max()))
         out["not_greedy"] += int(np.sum(prog.argmax(axis=1) != tokens))
         for cs, sel in zip(scores, sels):
             out["selection_gap"] = max(out["selection_gap"],
-                                       R.selection_gap(cs, sel, budget))
+                                       selection_gap(cs, sel, budget))
         out["tokens"] += len(served)
     return out

@@ -1,7 +1,6 @@
 """Model step: model FLOPs of the re-prefills the window finished, counted
-from their shapes, over the time from window start to the last first token,
-as a share of the chip's peak."""
-from chipbench import flops as F
+from their shapes by the configuration's architecture module, over the time
+from window start to the last first token, as a share of the chip's peak."""
 
 
 def read(run):
@@ -12,8 +11,8 @@ def read(run):
         t = r.trace
         periods = len(t.selected_per_period)
         selected = len(t.selected_per_period[0]) * run.page if periods else 0
-        work += F.reprefill_flops(run.dims, len(r.suffix), selected,
-                                  run.prefix_len, periods)
+        work += run.arch.reprefill_flops(run.dims, len(r.suffix), selected,
+                                         run.prefix_len, periods)
         last = max(last or 0.0, r.first_token_at)
     if last is None or last <= run.t0:
         return None

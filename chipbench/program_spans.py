@@ -154,7 +154,7 @@ def window(cell, seconds: float, seed: int, mode: str,
         if trace_dir is not None:
             shutil.rmtree(trace_dir, ignore_errors=True)
     rec = types.SimpleNamespace(
-        **run, setup_s=0.0, dims=cell.dims,
+        **run, setup_s=0.0, dims=cell.dims, arch=cell.arch,
         peak_flops=peaks["bf16_flops_per_s"], peak_bw=peaks["hbm_bytes_per_s"],
         page=cell.sysconf["chunk_tokens"], prefix_len=len(cell.prefix),
         trace=None, raw_trace=None)
@@ -180,7 +180,8 @@ def main(argv=None) -> int:
 
     bench = RUN.load_bench()
     try:
-        cell_entry, conf, mix, _ = RUN.resolve(bench, args.workload, False)
+        cell_entry, conf, mix, _, arch = RUN.resolve(bench, args.workload,
+                                                     False)
         device = RUN.device_info(cell_entry["chips"])
         RUN.use_compile_cache(RUN.import_system())
     except RUN.SetupError as e:
@@ -191,7 +192,7 @@ def main(argv=None) -> int:
               and args.workload in m.get("workloads", [args.workload])
               and m["name"] != "setup_s"]
     names = [m["name"] for m in wanted]
-    cell = C.Cell(conf, mix, args.seeds[0])
+    cell = C.Cell(conf, mix, args.seeds[0], arch)
     cell.build()
     cell.warm_up()
     RUN.say(f"set-up {time.perf_counter() - t_start:.3f} s")

@@ -1,4 +1,27 @@
-"""The served model's weights, its plain reference, and the reference's control.
+"""The dense architecture: weights, plain reference, control and FLOP counts.
+
+A configuration file names the module that defines its architecture for the
+benchmark in its optional key ``"reference"`` (a path from the repository
+root, under one of ``BENCHMARK.json``'s ``paths``); without the key it is
+this file.  ``run.architecture`` loads it, and the harness reaches weights,
+the reference and FLOP counts only through it.  Such a module gives:
+
+- ``dims_of(conf)``: the sizes the readers and the traffic use, at least
+  ``layers`` and ``vocab``, and whatever its own counts take;
+- ``program_config(conf)``: the serving code's ``ModelConfig``;
+- ``make_params(conf, seed)``: the weights, in the program's layout;
+- ``Reference(params, conf, prefix, quant=False)``, whose
+  ``.request(suffix, served, selections, total)`` returns ``(logits,
+  chunk scores per period, selection per period)``; ``quant=True`` is the
+  control;
+- ``reprefill_flops(dims, suffix, selected_tokens, prefix, periods)``,
+  ``decode_token_flops(dims, attended)`` and ``decode_attention_cost(dims,
+  valid_tokens, *, page, resident_pages, table_width)``.
+
+This module is the dense Qwen2-style block: GQA attention with optional q/k/v
+biases and q/k norms, RoPE on every layer, a SwiGLU FFN.  Only
+``program_config`` imports the serving code, when it is called; the weights,
+the reference and the counts import nothing of it.
 
 ``make_params`` draws the weights both sides are given, on the device and
 in bfloat16 (the served type), in one jitted call from the seed.  The
@@ -17,12 +40,18 @@ fp8 (e4m3): weights per output channel, activations per row and KV per
 token and head are rounded before each use.  That is the control.
 
 It runs layer by layer so that it fits beside the bfloat16 weights.
+
+The FLOP counts count what the algorithm needs (multiply-adds as 2), not
+what a kernel happens to execute: masked columns, padding and recomputation
+do not count.  ``dims`` is ``dims_of`` of a configuration file.
 """
 from __future__ import annotations
 
 import collections
+import dataclasses
 import math
 from functools import partial
+from typing import Iterable
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +70,20 @@ def dims_of(conf: dict) -> Dims:
                 theta=float(conf["rope_theta"]),
                 qk_norm=bool(conf["qk_norm"]),
                 qkv_bias=bool(conf["qkv_bias"]))
+
+
+def program_config(conf: dict):
+    """The serving code's ModelConfig, with every size from the file."""
+    from repro.configs import get_config
+
+    d = dims_of(conf)
+    return dataclasses.replace(
+        get_config(conf["arch"]), n_layers=d.layers, d_model=d.d,
+        n_heads=d.nq, n_kv_heads=d.nkv, d_head=d.dh, d_ff=d.ff,
+        vocab_size=d.vocab, qk_norm=d.qk_norm, qkv_bias=d.qkv_bias,
+        rope_theta=d.theta, norm_eps=d.eps,
+        tie_embeddings=bool(conf["tie_word_embeddings"]),
+        dtype=conf["torch_dtype"])
 
 
 def jax_seed(seed: int) -> int:
@@ -279,17 +322,62 @@ class Reference:
         return np.asarray(logits)[: len(served)], scores, used
 
 
-def token_gaps(ref_logits: np.ndarray, tokens: np.ndarray) -> np.ndarray:
-    """How far each token's reference logit lies below the reference's best."""
-    rows = np.arange(len(tokens))
-    return ref_logits.max(axis=1) - ref_logits[rows, tokens]
+# ---------------------------------------------------------------------------
+# FLOP and byte counts
+# ---------------------------------------------------------------------------
+def layer_matmul_flops(dims, tokens: int) -> float:
+    """QKV and output projections plus the SwiGLU FFN of one layer."""
+    d, nq, nkv, dh, ff = dims.d, dims.nq, dims.nkv, dims.dh, dims.ff
+    proj = d * (nq * dh + 2 * nkv * dh) + nq * dh * d
+    return 2.0 * tokens * (proj + 3 * d * ff)
 
 
-def selection_gap(ref_scores: np.ndarray, selected: np.ndarray,
-                  budget: float) -> float:
-    """How far the weakest selected chunk's reference score lies below the
-    k-th best reference score, in units of the mean chunk score."""
-    k = min(len(ref_scores), max(1, math.ceil(budget * len(ref_scores))))
-    kth = np.sort(ref_scores)[::-1][k - 1]
-    weakest = ref_scores[np.asarray(selected, int)].min()
-    return float(max(0.0, kth - weakest) / ref_scores.mean())
+def attention_flops(dims, query_key_pairs: float) -> float:
+    """Scores and weighted values over the given (query, key) pairs."""
+    return 4.0 * dims.nq * dims.dh * query_key_pairs
+
+
+def logits_flops(dims) -> float:
+    """The output head at one position."""
+    return 2.0 * dims.d * dims.vocab
+
+
+def reprefill_flops(dims, suffix: int, selected_tokens: int,
+                    prefix: int, periods: int) -> float:
+    """One re-prefill: identification against the whole prefix once per
+    period, then every layer attends over the selected chunks and the
+    suffix (causally), and the first token's logits."""
+    pairs = suffix * selected_tokens + suffix * (suffix + 1) / 2
+    identify = periods * 2.0 * suffix * dims.nq * dims.dh * prefix
+    return (dims.layers * (layer_matmul_flops(dims, suffix)
+                           + attention_flops(dims, pairs))
+            + identify + logits_flops(dims))
+
+
+def decode_token_flops(dims, attended: int) -> float:
+    """One decode step of one request that attends over `attended` tokens
+    in every layer."""
+    return (dims.layers * (layer_matmul_flops(dims, 1)
+                           + attention_flops(dims, attended))
+            + logits_flops(dims))
+
+
+def decode_attention_cost(dims, valid_tokens: Iterable[int], *,
+                          page: int, resident_pages: int, table_width: int,
+                          itemsize: int = 2):
+    """(flops, bytes) of one paged decode-attention call over a batch.
+
+    ``valid_tokens[i]`` is request i's attended length: its resident pages
+    hold ``resident_pages * page`` tokens, its tail the rest.  The kernel
+    reads each active page once (K and V, every KV head), one query and one
+    output row per query head, and writes the per-page mass."""
+    nq, nkv, dh = dims.nq, dims.nkv, dims.dh
+    flops = byts = 0.0
+    for n in valid_tokens:
+        tail = n - resident_pages * page
+        pages = resident_pages + math.ceil(tail / page)
+        flops += attention_flops(dims, n)
+        byts += 2 * pages * page * nkv * dh * itemsize  # K and V pages
+        byts += 2 * nq * dh * itemsize  # q in, out
+        byts += nq * table_width * 4  # fp32 per-page mass
+    return flops, byts

@@ -5,7 +5,9 @@
 
 One process: check the device, build the cell's model and prefix store from
 the seed, warm every shape, serve the traffic mix for ``--seconds``, then
-compare what was served with the plain reference.  The last line of
+compare what was served with the plain reference.  Weights, reference and
+FLOP counts come from the configuration's architecture module
+(``architecture``).  The last line of
 standard output is the JSON result; with ``--trace 0`` its metrics are the
 cell's end-to-end metrics, with ``--trace 1`` its per-layer metrics, read
 from a profiler trace of the window and from the run's counters.  The last
@@ -25,6 +27,7 @@ import argparse
 import importlib.util
 import json
 import pathlib
+import re
 import shutil
 import sys
 import tempfile
@@ -33,6 +36,15 @@ import types
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parent
+
+
+# A configuration file's key that names its architecture module (a path from
+# the repository root), the module without the key, and what the module
+# gives (the contract is in the default module's docstring)
+ARCH_KEY = "reference"
+DEFAULT_ARCH = "chipbench/reference.py"
+ARCH_NAMES = ("dims_of", "program_config", "make_params", "Reference",
+              "reprefill_flops", "decode_token_flops", "decode_attention_cost")
 
 
 class SetupError(Exception):
@@ -44,7 +56,8 @@ def load_bench(root: pathlib.Path = ROOT) -> dict:
 
 
 def resolve(bench: dict, workload: str, trace: bool):
-    """(cell, configuration file, traffic mix, metric entries) of a cell."""
+    """(cell, configuration file, traffic mix, metric entries, architecture
+    module) of a cell."""
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
         raise SetupError(f"no workload {workload!r} in BENCHMARK.json")
@@ -55,7 +68,36 @@ def resolve(bench: dict, workload: str, trace: bool):
     wanted = bench["per_layer"] if trace else bench["end_to_end"]
     metrics = [m for m in wanted
                if "workloads" not in m or workload in m["workloads"]]
-    return cell, conf, mix, metrics
+    return cell, conf, mix, metrics, architecture(bench, conf)
+
+
+def architecture(bench: dict, conf: dict):
+    """The module that defines the configuration's architecture: the file
+    that its ``"reference"`` key names, or ``chipbench/reference.py``.  The
+    file has to lie under one of the benchmark's ``paths`` and give every
+    name of ``ARCH_NAMES``.  A file is loaded once a process."""
+    rel = conf.get(ARCH_KEY, DEFAULT_ARCH)
+    where = f"configuration key {ARCH_KEY!r}: {rel!r}"
+    if not isinstance(rel, str):
+        raise SetupError(f"{where} is not a path")
+    path = (ROOT / rel).resolve()
+    if not any(path.is_relative_to((ROOT / p).resolve())
+               for p in bench["paths"]):
+        raise SetupError(f"{where} lies outside the benchmark's paths "
+                         f"{bench['paths']}")
+    if not path.is_file():
+        raise SetupError(f"{where} does not exist")
+    name = "chipbench_arch_" + re.sub(r"\W", "_", rel)
+    mod = sys.modules.get(name)
+    if mod is None or mod.__file__ != str(path):
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        spec.loader.exec_module(mod)
+    missing = [n for n in ARCH_NAMES if not hasattr(mod, n)]
+    if missing:
+        raise SetupError(f"{where} does not define {', '.join(missing)}")
+    return mod
 
 
 def reader(name: str):
@@ -130,12 +172,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     from chipbench import cell as C
     from chipbench import trace_reduce as TR
 
-    cell_entry, conf, mix, metrics = resolve(load_bench(), workload, trace)
+    cell_entry, conf, mix, metrics, arch = resolve(load_bench(), workload,
+                                                   trace)
     device = device_info(cell_entry["chips"])
     use_compile_cache(import_system())
     counter = C.CompileCounter()
 
-    cell = C.Cell(conf, mix, seed)
+    cell = C.Cell(conf, mix, seed, arch)
     steps = cell.build()
     t = time.perf_counter()
     cell.warm_up()
@@ -175,7 +218,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         failed = sum(1 for r in due if not r.done)
     peaks = device["peaks"]
     rec = types.SimpleNamespace(
-        **run, setup_s=setup_s, dims=cell.dims,
+        **run, setup_s=setup_s, dims=cell.dims, arch=arch,
         peak_flops=peaks["bf16_flops_per_s"], peak_bw=peaks["hbm_bytes_per_s"],
         page=conf["system"]["chunk_tokens"], prefix_len=len(cell.prefix),
         trace=reduced, raw_trace=raw)

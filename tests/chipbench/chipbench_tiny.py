@@ -45,15 +45,17 @@ def tiny_conf() -> dict:
     return conf
 
 
-def steer(monkeypatch, kind: str = "reprefill", **mix_keys):
-    """Point the harness at the tiny configuration and a fake device; returns
-    the cell name to run."""
-    conf, mix = tiny_conf(), dict(copy.deepcopy(MIXES[kind]), **mix_keys)
+def steer(monkeypatch, kind: str = "reprefill", conf: dict = None,
+          **mix_keys):
+    """Point the harness at the tiny configuration (or `conf`) and a fake
+    device; returns the cell name to run."""
+    conf = tiny_conf() if conf is None else conf
+    mix = dict(copy.deepcopy(MIXES[kind]), **mix_keys)
     real = RUN.resolve
 
     def resolve(bench, workload, trace):
-        cell, _, _, metrics = real(bench, workload, trace)
-        return cell, conf, mix, metrics
+        cell, _, _, metrics, _ = real(bench, workload, trace)
+        return cell, conf, mix, metrics, RUN.architecture(bench, conf)
 
     monkeypatch.setattr(RUN, "resolve", resolve)
     monkeypatch.setattr(RUN, "device_info", lambda chips: dict(FAKE_DEVICE))

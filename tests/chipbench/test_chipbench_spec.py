@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import json
+import pathlib
 import re
 
 import pytest
@@ -62,6 +63,12 @@ def test_configs_resolve(bench):
         assert set(conf["limits"]) == {"logit_err", "selection_gap",
                                        "not_greedy"}
         assert conf["limits"]["not_greedy"] == 0
+        # the architecture module, by the key or by default, in chipbench/
+        rel = conf.get("reference", "chipbench/reference.py")
+        arch = RUN.architecture(bench, conf)
+        assert pathlib.Path(arch.__file__) == ROOT / rel
+        assert pathlib.Path(rel).parts[0] == "chipbench"
+        assert all(callable(getattr(arch, n, None)) for n in RUN.ARCH_NAMES)
 
 
 def test_workloads_resolve(bench):

@@ -46,7 +46,8 @@ def test_tokens_and_gaps_count_inside_the_window_only():
     assert stats.tokens_in_window(run) == [100.0, 100.5, 101.0, 102.0, 104.0]
     assert RUN.reader("output_tok_s").read(run) == pytest.approx(5 / 10)
     assert sorted(stats.gaps_in_window(run)) == [0.5, 1.0, 2.0]
-    assert RUN.reader("itl_p95_ms").read(run) == pytest.approx(
+    assert RUN.reader("itl_p50_ms").read(run) == pytest.approx(1.0e3)
+    assert RUN.reader("itl_p95_ms.decode").read(run) == pytest.approx(
         np.percentile([0.5, 1.0, 2.0], 95) * 1e3)
 
 
@@ -62,7 +63,9 @@ def test_decode_batch_mean_counts_lone_steps_as_iterations():
 def test_ttft_is_unchanged_by_a_warm_up_on_the_same_executor():
     """The executor's clock starts at its construction; a window that opens
     seconds later must not count that age in any request's TTFT."""
-    cell = C.Cell(tiny_conf(), dict(MIXES["reprefill"]), 2**31 + 5)
+    conf = tiny_conf()
+    cell = C.Cell(conf, dict(MIXES["reprefill"]), 2**31 + 5,
+                  RUN.architecture(RUN.load_bench(), conf))
     cell.build()
     cell.warm_up()
     time.sleep(1.5)  # the executor is now older than any request's service
